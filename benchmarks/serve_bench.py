@@ -52,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_NAMES, get_reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.models.lm import extend_caches
 from repro.serve import QueueFull, ServeEngine
@@ -305,6 +306,7 @@ def main() -> None:
     if args.quick:
         for k, v in QUICK.items():
             setattr(args, k, v)
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch)
     model = build_model(cfg)

@@ -20,6 +20,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCH_NAMES, get_config, get_reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serve import QueueFull, ServeEngine
 
@@ -41,6 +42,7 @@ def main() -> None:
                     help="per-request TTFT deadline (seconds)")
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.full else get_reduced(args.arch)
     model = build_model(cfg)

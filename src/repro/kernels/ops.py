@@ -1,9 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
 These adapt the model-layer layouts ((B, S, H, Dh) activations) to the
-kernel layouts, pick block sizes, and fall back to interpret mode off-TPU
-(so the same call sites work in CPU tests; the dry-run lowers the jnp
-reference path instead — see DESIGN.md §6).
+kernel layouts and pick block sizes. They compile for the TPU; a caller off
+the chip (the CPU tests) passes ``interpret=True`` explicitly, so a kernel
+never drops into the Pallas interpreter unasked (DESIGN.md §6).
 """
 from __future__ import annotations
 
@@ -17,12 +17,8 @@ from .flash_attention import flash_attention_bhsd
 from .ssd import ssd_bshp
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(
-    jax.jit, static_argnames=("causal", "window", "block_q", "block_k")
+    jax.jit, static_argnames=("causal", "window", "block_q", "block_k", "interpret")
 )
 def flash_attention(
     q: jax.Array,  # (B, Sq, H, Dh) — model layout
@@ -34,6 +30,7 @@ def flash_attention(
     window: Optional[int] = None,
     block_q: int = 128,
     block_k: int = 128,
+    interpret: bool = False,
 ) -> jax.Array:
     del bias
     qt = q.transpose(0, 2, 1, 3)
@@ -47,12 +44,12 @@ def flash_attention(
         window=window,
         block_q=block_q,
         block_k=block_k,
-        interpret=not _on_tpu(),
+        interpret=interpret,
     )
     return out.transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk",))
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd(
     x: jax.Array,  # (B, S, H, P)
     dt: jax.Array,  # (B, S, H)
@@ -61,5 +58,6 @@ def ssd(
     Cm: jax.Array,  # (B, S, N)
     *,
     chunk: int = 64,
+    interpret: bool = False,
 ) -> jax.Array:
-    return ssd_bshp(x, dt, A, Bm, Cm, chunk=chunk, interpret=not _on_tpu())
+    return ssd_bshp(x, dt, A, Bm, Cm, chunk=chunk, interpret=interpret)

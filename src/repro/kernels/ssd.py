@@ -3,16 +3,25 @@
 The SSD chunked scan is two matmul-shaped contractions per chunk plus a tiny
 sequential state recurrence — ideal MXU work if the chunk is tiled into VMEM.
 
-Grid: (batch, num_chunks). TPU grids execute sequentially (row-major, last
-dim fastest), so the inter-chunk state carry lives in a VMEM scratch buffer
-(H, P, N) f32 that persists across the chunk axis and is reset whenever a
-new batch row begins — the same scratch-as-carry idiom as the flash kernel.
+Grid: (batch, head_blocks, num_chunks). TPU grids execute sequentially
+(row-major, last dim fastest), so the inter-chunk state carry lives in a
+VMEM scratch buffer (hb, P, N) f32 that persists across the chunk axis and
+is reset whenever a new (batch, head block) begins — the same
+scratch-as-carry idiom as the flash kernel. The head-block axis keeps the
+per-step (cl, cl) decay tiles of one block, not of every head, in VMEM.
 
-Per grid step, with one (chunk × heads) tile resident in VMEM:
-  L       = exp(segsum(dt*A))                 (H, cl, cl) intra-chunk decay
-  y_intra = (C Bᵀ ∘ L) @ (dt*x)               batched (cl,cl)@(cl,P) per head
-  y_inter = (C @ state_prev) * in_decay        (cl,N)@(N,P) per head
-  state   = state_prev * chunk_decay + (decay_to_end * B)ᵀ @ (dt*x)
+The wrapper discretizes outside the kernel (``xdt = dt * x``, ``dA = dt *
+A``, heads-major layout) so the kernel body is plain 2-D tiles. Per head,
+with one chunk resident in VMEM:
+  cum     = dA @ U                   inclusive prefix sum as a triangular
+                                     matmul (Pallas TPU lowers no cumsum)
+  L       = exp(cum_i - cum_j), j<=i (cl, cl) intra-chunk decay
+  y_intra = (C Bᵀ ∘ L) @ xdt         (cl,cl)@(cl,P)
+  y_inter = (C @ stateᵀ) * exp(cum)  (cl,N)@(N,P)
+  state   = state * exp(Σ dA) + (exp(suffix) * xdt)ᵀ @ B
+where suffix_i = Σ_{k>i} dA_k. The chunk total and the suffix sums are
+matmuls too, so every sum comes out as a whole row or column (Mosaic will
+not broadcast a single element across both sublanes and lanes).
 
 The pure-jnp oracle is models/ssm.ssd_reference (re-exported in ref.py).
 """
@@ -25,70 +34,57 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(
+        a, b, (dims, ((), ())), precision=_HI, preferred_element_type=jnp.float32
+    )
+
 
 def _kernel(
-    x_ref,  # (1, cl, H, P)
-    dt_ref,  # (1, cl, H) f32
-    a_ref,  # (H,) f32
+    xdt_ref,  # (1, hb, cl, P) f32, dt-weighted inputs
+    da_ref,  # (1, hb, cl) f32, dt * A
     b_ref,  # (1, cl, N)
     c_ref,  # (1, cl, N)
-    y_ref,  # (1, cl, H, P)
-    state_scr,  # (H, P, N) f32 carry across chunks
+    y_ref,  # (1, hb, cl, P)
+    state_scr,  # (hb, P, N) f32 carry across chunks
     *,
     cl: int,
+    hb: int,
 ):
-    ci = pl.program_id(1)
-
-    @pl.when(ci == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _reset():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    x = x_ref[0].astype(jnp.float32)  # (cl, H, P)
-    dt = dt_ref[0].astype(jnp.float32)  # (cl, H)
-    A = a_ref[...].astype(jnp.float32)  # (H,)
+    row = jax.lax.broadcasted_iota(jnp.int32, (cl, cl), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (cl, cl), 1)
+    tril = row >= col  # tril[i, j]: j <= i
+    lower = tril.astype(jnp.float32)
+    upper = (row <= col).astype(jnp.float32)  # upper[j, i]: j <= i
+    later = (row < col).astype(jnp.float32)  # later[i, k]: k > i
+    ones = jnp.ones((cl, b_ref.shape[-1]), jnp.float32)
+
     Bm = b_ref[0].astype(jnp.float32)  # (cl, N)
     Cm = c_ref[0].astype(jnp.float32)  # (cl, N)
+    scores = _dot(Cm, Bm, ((1,), (1,)))  # (cl, cl) = C Bᵀ, shared by heads
+    da = da_ref[0]  # (hb, cl)
 
-    dA = dt * A[None, :]  # (cl, H)
-    dA_cum = jnp.cumsum(dA, axis=0)  # (cl, H)
-    xdt = x * dt[..., None]  # (cl, H, P)
-
-    # intra-chunk: y[i] = sum_{j<=i} C_i·B_j exp(dA_cum_i - dA_cum_j) xdt_j
-    seg = dA_cum.T[:, :, None] - dA_cum.T[:, None, :]  # (H, cl, cl)
-    tril = (
-        jax.lax.broadcasted_iota(jnp.int32, (cl, cl), 0)
-        >= jax.lax.broadcasted_iota(jnp.int32, (cl, cl), 1)
-    )
-    L = jnp.where(tril[None], jnp.exp(seg), 0.0)  # (H, cl, cl)
-    scores = jax.lax.dot_general(
-        Cm, Bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (cl, cl)
-    M = scores[None] * L  # (H, cl, cl)
-    xdt_h = xdt.transpose(1, 0, 2)  # (H, cl, P)
-    y_intra = jax.lax.dot_general(
-        M, xdt_h, (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
-    )  # (H, cl, P)
-
-    # inter-chunk: y[i] += (C_i @ state_prev_h) * exp(dA_cum_i)
-    state = state_scr[...]  # (H, P, N)
-    y_inter = jax.lax.dot_general(
-        jnp.broadcast_to(Cm[None], (state.shape[0], cl, Cm.shape[1])),
-        state,
-        (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )  # (H, cl, P)
-    in_decay = jnp.exp(dA_cum).T  # (H, cl)
-    y = y_intra + y_inter * in_decay[:, :, None]
-    y_ref[0] = y.transpose(1, 0, 2).astype(y_ref.dtype)  # (cl, H, P)
-
-    # state update
-    chunk_decay = jnp.exp(dA_cum[-1, :])  # (H,)
-    decay_to_end = jnp.exp(dA_cum[-1:, :] - dA_cum)  # (cl, H)
-    bw = Bm[None, :, :] * decay_to_end.T[:, :, None]  # (H, cl, N)
-    new_contrib = jax.lax.dot_general(
-        xdt_h, bw, (((1,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
-    )  # (H, P, N)
-    state_scr[...] = state * chunk_decay[:, None, None] + new_contrib
+    for h in range(hb):
+        da_h = da[h : h + 1, :]  # (1, cl)
+        cum_row = _dot(da_h, upper, ((1,), (0,)))  # (1, cl)
+        cum_col = _dot(lower, da_h, ((1,), (1,)))  # (cl, 1)
+        suffix = _dot(later, da_h, ((1,), (1,)))  # (cl, 1)
+        total = _dot(da_h, ones, ((1,), (0,)))  # (1, N), every lane the sum
+        L = jnp.where(tril, jnp.exp(cum_col - cum_row), 0.0)  # (cl, cl)
+        xdt = xdt_ref[0, h]  # (cl, P)
+        y = _dot(scores * L, xdt, ((1,), (0,)))  # (cl, P)
+        state = state_scr[h]  # (P, N)
+        y = y + _dot(Cm, state, ((1,), (1,))) * jnp.exp(cum_col)
+        y_ref[0, h] = y.astype(y_ref.dtype)
+        xw = xdt * jnp.exp(suffix)  # (cl, P)
+        state_scr[h] = state * jnp.exp(total) + _dot(xw, Bm, ((0,), (0,)))
 
 
 def ssd_bshp(
@@ -106,21 +102,26 @@ def ssd_bshp(
     cl = min(chunk, S)
     assert S % cl == 0, (S, cl)
     nc = S // cl
+    # a head block is a sublane tile (8) where H allows, else every head
+    hb = 8 if H % 8 == 0 else H
 
-    kernel = functools.partial(_kernel, cl=cl)
+    dt = dt.astype(jnp.float32)
+    xdt = (x.astype(jnp.float32) * dt[..., None]).transpose(0, 2, 1, 3)  # (B,H,S,P)
+    da = (dt * A.astype(jnp.float32)).transpose(0, 2, 1)  # (B,H,S)
+
+    kernel = functools.partial(_kernel, cl=cl, hb=hb)
     out = pl.pallas_call(
         kernel,
-        grid=(B, nc),
+        grid=(B, H // hb, nc),
         in_specs=[
-            pl.BlockSpec((1, cl, H, P), lambda b, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, cl, H), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((H,), lambda b, c: (0,)),
-            pl.BlockSpec((1, cl, N), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, cl, N), lambda b, c: (b, c, 0)),
+            pl.BlockSpec((1, hb, cl, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, hb, cl), lambda b, h, c: (b, h, c)),
+            pl.BlockSpec((1, cl, N), lambda b, h, c: (b, c, 0)),
+            pl.BlockSpec((1, cl, N), lambda b, h, c: (b, c, 0)),
         ],
-        out_specs=pl.BlockSpec((1, cl, H, P), lambda b, c: (b, c, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, H, P), x.dtype),
-        scratch_shapes=[pltpu.VMEM((H, P, N), jnp.float32)],
+        out_specs=pl.BlockSpec((1, hb, cl, P), lambda b, h, c: (b, h, c, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, P), x.dtype),
+        scratch_shapes=[pltpu.VMEM((hb, P, N), jnp.float32)],
         interpret=interpret,
-    )(x, dt.astype(jnp.float32), A.astype(jnp.float32), Bm, Cm)
-    return out
+    )(xdt, da, Bm, Cm)
+    return out.transpose(0, 2, 1, 3)

@@ -12,6 +12,7 @@ from __future__ import annotations
 import argparse
 
 from repro.configs import ARCH_NAMES, get_config, get_reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import Trainer, TrainerConfig
 
 
@@ -28,6 +29,7 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--fail-at", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     tcfg = TrainerConfig(

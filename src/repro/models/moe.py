@@ -207,14 +207,12 @@ def moe_ep(cfg, p: dict, x: jax.Array, ctx) -> Tuple[jax.Array, jax.Array]:
         y2d = _combine_local(y_buf, w_l.reshape(Tl, K), slot, keep)
         return y2d.reshape(Bl, Sl, d)
 
-    from repro.parallel.ctx import shard_map
-
-    y = shard_map(
+    y = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(x_spec, x_spec, x_spec, wg_spec, wg_spec, wd_spec),
         out_specs=x_spec,
-        check_rep=False,  # checkpoint_name has no replication rule on 0.4.x
+        check_vma=False,  # no varying-axes rule for checkpoint_name
     )(x, weights, ids, p["w_gate"], p["w_up"], p["w_down"])
 
     if cfg.num_shared_experts:

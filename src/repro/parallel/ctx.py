@@ -13,21 +13,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool = True):
-    """Version-portable ``shard_map``.
-
-    Public ``jax.shard_map`` where available; the experimental module on the
-    pinned 0.4.x line. ``check_rep=False`` is forwarded only to the
-    experimental API — its replication checker has no rule for the
-    ``checkpoint_name`` primitive the MoE path tags collectives with.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_rep)
-
-
 @dataclass(frozen=True)
 class ParallelCtx:
     mesh: Mesh

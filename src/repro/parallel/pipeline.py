@@ -30,9 +30,6 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from repro.core.schedule import simulate
-from repro.parallel.ctx import shard_map
-
-_HAS_PUBLIC_SHARD_MAP = hasattr(jax, "shard_map")
 
 
 def forward_tick_table(num_stages: int, num_microbatches: int) -> np.ndarray:
@@ -116,34 +113,18 @@ def build_pipelined_loss(
             nxt = jax.lax.ppermute(out, axis, [(i, (i + 1) % S) for i in range(S)])
             return (nxt, acc + contrib), None
 
-        # acc is carried as (1,), not a scalar: the legacy (0.4.x) shard_map
-        # transpose rule mis-specs scalar scan-carry residuals
-        buf0 = jnp.zeros(mb_shape, x_mb.dtype)
-        acc0 = jnp.zeros((1,), jnp.float32)
         # the carry becomes device-varying after the first ppermute; mark the
         # initial values as varying so the scan carry types are stable
-        if hasattr(jax.lax, "pcast"):
-            buf0 = jax.lax.pcast(buf0, (axis,), to="varying")
-            acc0 = jax.lax.pcast(acc0, (axis,), to="varying")
+        buf0 = jax.lax.pcast(jnp.zeros(mb_shape, x_mb.dtype), (axis,), to="varying")
+        acc0 = jax.lax.pcast(jnp.zeros((), jnp.float32), (axis,), to="varying")
         (buf, acc), _ = jax.lax.scan(tick, (buf0, acc0), jnp.arange(ticks))
-        # mean over microbatches, summed across stages (only last contributes)
-        total = jax.lax.psum(acc, axis) / num_microbatches  # (1,)
-        # legacy jax: return a per-stage copy (mapped out spec) because the
-        # 0.4.x replication checker cannot track the ppermute-varying carry
-        return total[0] if _HAS_PUBLIC_SHARD_MAP else total
-
-    # loss must come back identical on every rank: psum above handles it.
+        # mean over microbatches, summed across stages (only last contributes);
+        # the psum makes the loss identical on every rank
+        return jax.lax.psum(acc, axis) / num_microbatches
 
     def loss(params_stacked, x_mb, y_mb):
-        out = shard_map(
-            body,
-            mesh=mesh,
-            in_specs=(P(axis), P(), P()),
-            out_specs=P() if _HAS_PUBLIC_SHARD_MAP else P(axis),
-            check_rep=_HAS_PUBLIC_SHARD_MAP,
+        return jax.shard_map(
+            body, mesh=mesh, in_specs=(P(axis), P(), P()), out_specs=P()
         )(params_stacked, x_mb, y_mb)
-        # legacy: (S,) identical psum'ed copies — mean is value- and
-        # gradient-identical to the replicated scalar
-        return out if _HAS_PUBLIC_SHARD_MAP else out.mean()
 
     return loss, table

@@ -22,6 +22,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint import CheckpointManager
 from repro.core import ThreadPool
@@ -34,7 +36,7 @@ from repro.parallel.steps import build_train_step
 @dataclass
 class TrainerConfig:
     num_steps: int = 100
-    checkpoint_every: int = 20
+    checkpoint_every: int = 20  # 0: never save (a job with nothing to resume)
     log_every: int = 10
     seq_len: int = 128
     global_batch: int = 8
@@ -75,15 +77,30 @@ class Trainer:
 
     # -- state --------------------------------------------------------------------
 
-    def init_state(self) -> dict:
-        params = self.model.init(jax.random.PRNGKey(self.tcfg.seed))
-        return {
-            "params": params,
-            "opt": adamw_init(self.ocfg, params),
-            "step": jnp.zeros((), jnp.int32),
-        }
+    def init_state(self, shardings=None) -> dict:
+        """Fresh params and AdamW state.
+
+        ``shardings`` (a ``NamedSharding`` tree shaped like the state) builds
+        every leaf in place on the mesh, so no single device ever holds the
+        whole state — TinyLlama-1.1B's params plus AdamW state outgrow one
+        16 GB chip.
+        """
+
+        def init(key):
+            params = self.model.init(key)
+            return {
+                "params": params,
+                "opt": adamw_init(self.ocfg, params),
+                "step": jnp.zeros((), jnp.int32),
+            }
+
+        key = jax.random.PRNGKey(self.tcfg.seed)
+        if shardings is None:
+            return init(key)
+        return jax.jit(init, out_shardings=shardings)(key)
 
     def _build_step(self):
+        """Returns (jitted step, state shardings or None without a mesh)."""
         if self.mesh is not None:
             spec = {
                 "seq_len": self.tcfg.seq_len,
@@ -91,10 +108,15 @@ class Trainer:
                 "kind": "train",
             }
             batch_abstract = self.model.input_specs("train", spec)
-            step, shardings, _ = build_train_step(
+            step, specs, _ = build_train_step(
                 self.model, self.mesh, self.ocfg, self.lr_fn, batch_abstract, donate=False
             )
-            return step
+            shardings = jax.tree.map(
+                lambda sp: NamedSharding(self.mesh, sp),
+                {"params": specs["params"], "opt": specs["opt"], "step": P()},
+                is_leaf=lambda x: isinstance(x, P),
+            )
+            return step, shardings
 
         def step_fn(params, opt_state, batch, step):
             from repro.optim import adamw_update
@@ -106,21 +128,22 @@ class Trainer:
             new_params, new_opt, om = adamw_update(self.ocfg, lr, params, grads, opt_state)
             return new_params, new_opt, {"loss": loss, **metrics, **om}
 
-        return jax.jit(step_fn)
+        return jax.jit(step_fn), None
 
     # -- run -----------------------------------------------------------------------
 
     def run(self, *, resume: bool = True) -> dict:
-        state = self.init_state()
+        step_fn, shardings = self._build_step()
+        state = self.init_state(shardings)
         start_step = 0
         if resume and self.ckpt.latest_step() is not None:
-            state, meta = self.ckpt.restore(state)
+            state, meta = self.ckpt.restore(state, shardings=shardings)
             start_step = int(meta["step"])
-        step_fn = self._build_step()
         prefetch = Prefetcher(
             self.data, pool=self.pool, depth=self.tcfg.prefetch_depth, start_step=start_step
         )
         params, opt = state["params"], state["opt"]
+        every = self.tcfg.checkpoint_every
         try:
             for step in range(start_step, self.tcfg.num_steps):
                 self._check_heartbeat()
@@ -138,14 +161,14 @@ class Trainer:
                     row = {k: float(v) for k, v in metrics.items()}
                     row["step"] = step
                     self.metrics_log.append(row)
-                if (step + 1) % self.tcfg.checkpoint_every == 0:
+                if every and (step + 1) % every == 0:
                     self.ckpt.save_async(
                         step + 1,
                         {"params": params, "opt": opt, "step": jnp.asarray(step + 1)},
                         meta={"step": step + 1, "cursor": prefetch.cursor},
                     )
             # final checkpoint (skip if the loop just saved this step)
-            if self.tcfg.num_steps % self.tcfg.checkpoint_every != 0:
+            if every and self.tcfg.num_steps % every != 0:
                 self.ckpt.save_async(
                     self.tcfg.num_steps,
                     {"params": params, "opt": opt, "step": jnp.asarray(self.tcfg.num_steps)},

@@ -49,7 +49,9 @@ def test_parser_ignores_async_done_pairs():
 
 def test_parser_on_real_lowering():
     """End-to-end: a sharded matmul must show a psum in the parsed traffic."""
-    mesh = jax.make_mesh((1,), ("model",))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1,), ("model",), devices=jax.devices()[:1])
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     @jax.jit

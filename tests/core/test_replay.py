@@ -183,8 +183,8 @@ def test_cancellation_mid_replay_falls_back_live(tex):
 
     head = g.add(slow, name="head")
     g.then(head, lambda _: hits.append(2), name="tail")
+    release.set()  # pass 1 runs straight through; only the replay is held
     tex.run(g).result(10)
-    release.set()  # pass 1 may still be parked on the gate
     gate.clear()
     release.clear()
     fut = tex.run(g)  # replayed pass

@@ -86,7 +86,7 @@ def test_model_layout_wrapper():
     q = jax.random.normal(key, (B, S, H, Dh), jnp.float32)
     k = jax.random.normal(key, (B, S, KV, Dh), jnp.float32)
     v = jax.random.normal(key, (B, S, KV, Dh), jnp.float32)
-    got = ops.flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
+    got = ops.flash_attention(q, k, v, causal=True, block_q=64, block_k=64, interpret=True)
     want = attention_ref(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), causal=True
     ).transpose(0, 2, 1, 3)

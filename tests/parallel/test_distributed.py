@@ -37,6 +37,7 @@ def test_sharded_train_step_matches_single_device():
     from repro.configs import get_reduced
     from repro.models import build_model
     from repro.optim import AdamWConfig, adamw_init, adamw_update, cosine_schedule
+    from repro.launch.mesh import make_host_mesh
     from repro.parallel.steps import build_train_step
 
     cfg = get_reduced("tinyllama-1.1b").replace(dtype="float32", remat="none")
@@ -56,7 +57,7 @@ def test_sharded_train_step_matches_single_device():
         return p2, o2, loss
     rp, ro, rloss = jax.jit(ref_step)(params, opt, batch, jnp.asarray(0))
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh(model=4)
     spec = {"seq_len": S, "global_batch": B, "kind": "train"}
     step, shardings, abstract = build_train_step(
         model, mesh, ocfg, lr_fn, model.input_specs("train_4k", spec), donate=False)
@@ -78,6 +79,7 @@ def test_moe_ep_matches_dense_oracle():
     from repro.configs.base import ModelConfig
     from repro.models.common import Alloc
     from repro.models.moe import moe_params, moe_dense, moe_ep
+    from repro.launch.mesh import make_host_mesh
     from repro.parallel.ctx import ParallelCtx
 
     cfg = ModelConfig(name="m", family="moe", num_layers=1, d_model=32, num_heads=2,
@@ -87,7 +89,7 @@ def test_moe_ep_matches_dense_oracle():
                       dtype="float32")
     a = Alloc("init", jax.random.PRNGKey(0), dtype=jnp.float32)
     p = moe_params(cfg, a)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh(model=4)
     ctx = ParallelCtx(mesh, batch_axes=("data",))
     B, S, d = 4, 8, 32
     x = jax.random.normal(jax.random.PRNGKey(1), (B, S, d), jnp.float32)
@@ -114,17 +116,18 @@ def test_elastic_checkpoint_restore_across_meshes():
     import tempfile, jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.checkpoint import CheckpointManager
+    from repro.launch.mesh import make_host_mesh
 
     tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
             "b": jnp.ones((8,), jnp.bfloat16)}
     with tempfile.TemporaryDirectory() as d:
-        mesh1 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh1 = make_host_mesh(model=2)
         t1 = jax.device_put(tree, {"w": NamedSharding(mesh1, P("data", "model")),
                                    "b": NamedSharding(mesh1, P("data"))})
         with CheckpointManager(d, keep=2) as cm:
             cm.save_async(5, t1, meta={"step": 5})
             cm.wait()
-            mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+            mesh2 = make_host_mesh(model=4)
             shard2 = {"w": NamedSharding(mesh2, P("model", "data")), "b": None}
             restored, meta = cm.restore(tree, shardings=shard2)
             assert meta["step"] == 5
@@ -139,10 +142,11 @@ def test_pipeline_parallel_matches_serial():
     """Task-graph-scheduled pipeline (4 stages over 'pod') == serial model."""
     run_devices("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.parallel.pipeline import build_pipelined_loss, forward_tick_table
 
     S, M, W = 4, 8, 16  # stages, microbatches, width
-    mesh = jax.make_mesh((4,), ("pod",))
+    mesh = make_mesh((4,), ("pod",), devices=jax.devices()[:4])
     key = jax.random.PRNGKey(0)
     params = {"w": jax.random.normal(key, (S, W, W)) * 0.3,
               "b": jnp.zeros((S, W))}
@@ -185,6 +189,7 @@ def test_decode_step_sharded_matches_single_device():
     from repro.configs import get_reduced
     from repro.models import build_model
     from repro.models.lm import extend_caches
+    from repro.launch.mesh import make_host_mesh
     from repro.parallel.steps import build_decode_step
 
     cfg = get_reduced("granite-moe-1b-a400m").replace(dtype="float32")
@@ -197,7 +202,7 @@ def test_decode_step_sharded_matches_single_device():
     tok = jnp.zeros((B, 1), jnp.int32)
     ref_logits, _ = jax.jit(model.decode_step)(params, tok, caches, jnp.asarray(S))
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh(model=4)
     abstract = {"tokens": jax.ShapeDtypeStruct((B, 1), jnp.int32),
                 "caches": jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), caches),
                 "index": jax.ShapeDtypeStruct((), jnp.int32)}
