@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the chip, in %:
+1 - (union of device op intervals) / window."""
+
+
+def read(view):
+    if view.trace is None or view.trace["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - view.trace["busy_s"] / view.trace["window_s"])

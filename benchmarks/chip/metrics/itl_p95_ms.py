@@ -1,0 +1,8 @@
+"""95th percentile of every gap between consecutive streamed tokens of every
+request, over the gaps that close inside the window (host clock)."""
+import numpy as np
+
+
+def read(view):
+    gaps = [b - a for r in view.records for a, b in zip(r.times, r.times[1:])]
+    return 1e3 * float(np.percentile(gaps, 95)) if gaps else None

@@ -1,0 +1,262 @@
+"""The benchmark's yardstick on the CPU: trace reduction, operation and byte
+counts, traffic generation, and the harness's refusals."""
+import json
+import os
+import shutil
+import subprocess
+import sys
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+ROOT = Path(__file__).resolve().parents[2]
+BENCH = ROOT / "benchmarks" / "chip"
+sys.path[:0] = [str(BENCH), str(ROOT / "src")]
+
+import counts  # noqa: E402
+import harness  # noqa: E402
+import peaks  # noqa: E402
+import trace_reduce  # noqa: E402
+import traffic  # noqa: E402
+
+PROBE = Path(__file__).with_name("data") / "tpu_probe.xplane.pb"
+
+# A hand-made trace of one chip: a fusion runs over [1000, 5000) ns and
+# [8000, 10000), an all-reduce over [3000, 7000), inside one run of jit_step.
+COLLECTIVE_TRACE = """
+planes {
+  id: 1
+  name: "/device:TPU:0"
+  lines { id: 1 name: "XLA Modules" timestamp_ns: 1000
+    events { metadata_id: 10 offset_ps: 0 duration_ps: 10000000 }
+  }
+  lines { id: 2 name: "XLA Ops" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 4000000 }
+    events { metadata_id: 2 offset_ps: 2000000 duration_ps: 4000000 }
+    events { metadata_id: 1 offset_ps: 7000000 duration_ps: 2000000 }
+  }
+  event_metadata { key: 1 value { id: 1
+    name: "%fusion.1 = bf16[8]{0} fusion(bf16[8]{0} %a), kind=kLoop" } }
+  event_metadata { key: 2 value { id: 2
+    name: "%all-reduce.3 = f32[8]{0} all-reduce(f32[8]{0} %b), replica_groups={{0,1,2,3}}" } }
+  event_metadata { key: 10 value { id: 10 name: "jit_step(123)" } }
+}
+"""
+
+
+# -- trace reduction ------------------------------------------------------------
+
+
+def test_reduces_a_recorded_tpu_trace():
+    """A trace recorded on a v5e: two jitted programs run three times each;
+    host spans ``bench.mark:<i>`` sit on the same clock."""
+    tr = trace_reduce.load(str(PROBE), host_prefixes=("bench.mark",))
+    assert sorted(tr.devices) == [0]
+    assert [h[0] for h in tr.host] == ["bench.mark:0", "bench.mark:1", "bench.mark:2"]
+    dev = tr.devices[0]
+    assert [p.name for p in dev.programs] == ["jit__lambda"] * 6
+    lo, hi = dev.programs[0].start, dev.programs[-1].end
+    assert [p.dur for p in dev.programs] == [39902, 142792, 39967, 142362, 40183, 141525]
+    merged = trace_reduce.merge(((o.start, o.end) for o in dev.ops), lo, hi)
+    assert trace_reduce.busy_ns(dev, lo, hi) == trace_reduce.total(merged) == 546662
+    # busy lies inside the programs' own spans, and the gaps fill the rest
+    assert trace_reduce.total(merged) <= sum(p.dur for p in dev.programs)
+    gaps = trace_reduce.gaps(merged, lo, hi)
+    assert trace_reduce.total(gaps) + trace_reduce.total(merged) == hi - lo
+    assert max(e - s for s, e in gaps) == 3800695
+    assert trace_reduce.collective_ns(dev, lo, hi) == (0, 0)
+    top = trace_reduce.top_ops(dev, lo, hi, n=2)
+    assert [name for name, _ in top] == ["jit__lambda/%sort.6", "jit__lambda/%fusion.13"]
+    assert all(not name.endswith("while") for name, _ in trace_reduce.top_ops(dev, lo, hi))
+
+
+def test_collective_time_and_its_exposed_part(tmp_path):
+    from jax.profiler import ProfileData
+
+    path = tmp_path / "coll.xplane.pb"
+    path.write_bytes(ProfileData.text_proto_to_serialized_xspace(COLLECTIVE_TRACE))
+    dev = trace_reduce.load(str(path)).devices[0]
+    assert [(p.name, p.start, p.dur) for p in dev.programs] == [("jit_step", 1000, 10000)]
+    assert [o.opcode for o in dev.ops] == ["fusion", "all-reduce", "fusion"]
+    # the all-reduce runs 4000 ns, of which [5000, 7000) has no fusion beside it
+    assert trace_reduce.collective_ns(dev, 1000, 11000) == (4000, 2000)
+    assert trace_reduce.busy_ns(dev, 1000, 11000) == 8000
+    merged = trace_reduce.merge(((o.start, o.end) for o in dev.ops), 1000, 11000)
+    assert trace_reduce.gaps(merged, 1000, 11000) == [(7000, 8000), (10000, 11000)]
+
+
+def test_interval_union_clips_and_merges():
+    assert trace_reduce.merge([(5, 9), (0, 3), (2, 4), (8, 12)], 1, 10) == [(1, 4), (5, 10)]
+    assert trace_reduce.gaps([(1, 4), (5, 10)], 0, 12) == [(0, 1), (4, 5), (10, 12)]
+
+
+@pytest.mark.parametrize(
+    "text, label, opcode",
+    [
+        ("%fusion.13 = bf16[512,1024]{1,0} fusion(bf16[512,1024]{1,0} %c), kind=kOutput",
+         "%fusion.13", "fusion"),
+        ("%while = (s32[]{:T(128)}, bf16[4]{0}) while((s32[]{:T(128)}, bf16[4]{0}) %t)",
+         "%while", "while"),
+        ("%all-gather-start.2 = (bf16[8]{0}, bf16[32]{0}) all-gather-start(bf16[8]{0} %p)",
+         "%all-gather-start.2", "all-gather-start"),
+    ],
+)
+def test_op_labels(text, label, opcode):
+    assert trace_reduce.op_label(text) == (label, opcode)
+
+
+def test_program_name_drops_the_fingerprint():
+    assert trace_reduce.program_name("jit__ptick(1787815954494)") == "jit__ptick"
+
+
+# -- operations and bytes ----------------------------------------------------------
+
+
+def _phi4() -> counts.Dims:
+    conf = json.loads((BENCH / "configs" / "phi4-mini-3.8b.json").read_text())
+    return counts.Dims.from_published(conf["published"])
+
+
+def test_phi4_mini_counts_by_hand():
+    """Phi-4-mini unpadded: d 3072, 32 layers, 24/8 heads of 128, d_ff 8192,
+    vocab 200,064, tied."""
+    dm = _phi4()
+    embed = 200_064 * 3_072  # 614,596,608
+    attn = 3_072 * 24 * 128 * 2 + 2 * 3_072 * 8 * 128  # q, o; k, v: 25,165,824
+    mlp = 3 * 3_072 * 8_192  # 75,497,472
+    assert dm.embed_params == embed == 614_596_608
+    assert dm.layer_params == attn + mlp == 100_663_296
+    assert dm.non_embedding_params == 3_221_225_472
+    assert dm.total_params == 3_835_822_080
+    assert dm.matmul_params == dm.total_params  # tied: the head is the embedding
+    assert dm.weight_bytes == 7_671_644_160
+    assert dm.kv_bytes_per_token == 2 * 32 * 8 * 128 * 2 == 131_072
+    # one decoded token seeing 300 keys: 2 N + 4 L H Dh c
+    assert dm.decode_flops(300) == 2 * 3_835_822_080 + 4 * 32 * 24 * 128 * 300
+    # a 256-token prefill: every layer on every token, the head once, causal pairs
+    assert dm.prefill_flops(256) == (
+        2 * 3_221_225_472 * 256 + 2 * embed + 4 * 32 * 24 * 128 * (256 * 257 / 2)
+    )
+    assert dm.train_flops_per_token(1024) == 6 * 3_835_822_080 + 3 * 4 * 32 * 24 * 128 * 512.5
+
+
+def test_counts_match_the_repo_param_count():
+    from repro.configs import get_config, param_count
+
+    assert param_count(get_config("phi4-mini-3.8b"))["total"] == _phi4().total_params
+
+
+# -- traffic ----------------------------------------------------------------------
+
+
+def _chat() -> dict:
+    return json.loads((BENCH / "traffic" / "chat.json").read_text())
+
+
+def test_traffic_is_a_function_of_the_seed():
+    mix = _chat()
+    a = traffic.make_requests(mix, 5_000_000_123, 20.0, 200_064)
+    b = traffic.make_requests(mix, 5_000_000_123, 20.0, 200_064)
+    c = traffic.make_requests(mix, 5_000_000_124, 20.0, 200_064)
+    key = lambda rs: [(r.due, r.max_new, r.prompt.tobytes()) for r in rs]  # noqa: E731
+    assert key(a) == key(b)
+    assert key(a) != key(c)
+    # every seed replays the mix's one schedule of arrivals and sizes
+    shape = lambda rs: [(r.due, len(r.prompt), r.max_new) for r in rs]  # noqa: E731
+    assert shape(a) == shape(c)
+    assert all(x.prompt.tobytes() != y.prompt.tobytes() for x, y in zip(a, c))
+    other = traffic.make_requests(dict(mix, schedule_seed=1), 5_000_000_123, 20.0, 200_064)
+    assert [len(r.prompt) for r in other] != [len(r.prompt) for r in a]
+    assert sorted(len(r.prompt) for r in other) == sorted(len(r.prompt) for r in a)
+
+
+def test_open_loop_arrivals_fill_the_window():
+    mix = _chat()
+    rs = traffic.make_requests(mix, 7, 40.0, 200_064)
+    due = np.array([r.due for r in rs])
+    assert len(rs) == round(mix["rate_per_s"] * 40.0)
+    assert np.all(np.diff(due) > 0) and 0 < due[0] and due[-1] < 40.0
+    lens = np.array([len(r.prompt) for r in rs])
+    p = mix["prompt_len"]
+    assert lens.min() >= p["min"] and lens.max() <= p["max"]
+    assert abs(np.median(lens) - p["median"]) <= 0.05 * p["median"]
+    assert all(0 <= r.prompt.min() and r.prompt.max() < 200_064 for r in rs)
+
+
+@pytest.mark.parametrize("arrivals", ["gamma", "backlog"])
+def test_other_arrival_shapes(arrivals):
+    mix = dict(_chat(), arrivals=arrivals, gamma_shape=0.5, requests=12)
+    mix["shared_prefix"] = {"tokens": 16, "groups": 2}
+    rs = traffic.make_requests(mix, 3, 10.0, 1000)
+    if arrivals == "backlog":
+        assert len(rs) == 12 and all(r.due == 0 for r in rs)
+    else:
+        g = np.diff([0.0] + [r.due for r in rs])
+        assert g.std() > g.mean()  # burstier than Poisson
+    prefixes = {r.prompt[:16].tobytes() for r in rs}
+    assert len(prefixes) <= 2
+
+
+# -- refusals ----------------------------------------------------------------------
+
+
+def _run(cwd, extra_env=None):
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    env.update(JAX_PLATFORMS="cpu", **(extra_env or {}))
+    return subprocess.run(
+        [sys.executable, "benchmarks/chip/run.py", "--workload", "phi4mini.chat",
+         "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=cwd, env=env, capture_output=True, text=True, timeout=300,
+    )
+
+
+def test_refuses_a_cpu_and_prints_no_result():
+    p = _run(ROOT)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
+    assert "not a TPU" in p.stderr
+
+
+def test_refuses_without_the_program(tmp_path):
+    """A directory with BENCHMARK.json and the benchmark's files alone."""
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    shutil.copytree(BENCH, tmp_path / "benchmarks" / "chip")
+    p = _run(tmp_path)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
+
+
+def test_unknown_device_kind_is_an_error():
+    with pytest.raises(peaks.UnknownDevice):
+        peaks.peaks_for("TPU v9 imaginary")
+    assert peaks.peaks_for("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
+
+
+def test_device_check_refuses_other_chips(monkeypatch):
+    import jax
+
+    class Fake:
+        platform, device_kind = "tpu", "TPU v9 imaginary"
+
+    monkeypatch.setattr(jax, "devices", lambda: [Fake()])
+    with pytest.raises(peaks.UnknownDevice):
+        harness.device_info(1)
+    Fake.device_kind = "TPU v5 lite"
+    assert harness.device_info(1) == {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    with pytest.raises(harness.NoChip):
+        harness.device_info(4)
+    monkeypatch.setattr(jax, "devices", lambda: [type("Cpu", (), {"platform": "cpu"})()])
+    with pytest.raises(harness.NoChip):
+        harness.device_info(1)
+
+
+def test_every_cell_resolves_to_its_files():
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    for w in bench["workloads"]:
+        cell = harness.load_cell(w["name"])
+        assert (BENCH / "drivers" / f"{cell.config['entry']}.py").exists()
+        assert (BENCH / "refs" / f"{cell.config['reference']}.py").exists()
+        for m in cell.end_to_end + cell.per_layer:
+            assert (BENCH / "metrics" / f"{m['name']}.py").exists(), m["name"]
+        assert cell.model_config().num_layers == cell.published["num_hidden_layers"]
