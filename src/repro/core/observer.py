@@ -1,6 +1,6 @@
 """Observer layer for the thread pool (DESIGN.md §8).
 
-Taskflow-style executor observation: the pool exposes four lifecycle hooks
+Taskflow-style executor observation: the pool exposes six lifecycle hooks
 and calls every attached observer at each of them —
 
     on_submit(task)                 task entered a queue (inbox or deque)
@@ -36,7 +36,12 @@ Two implementations ship here:
 * :class:`StatsObserver` — aggregate counters and per-task-name timing;
 * :class:`ChromeTraceObserver` — a ``chrome://tracing`` / Perfetto trace
   exporter ("trace event format" JSON: one complete ``X`` event per task
-  execution on the worker's lane, instant events for steals).
+  execution on the worker's lane, instant events for steals). Besides the
+  hooks, its ``span(name, **args)`` context manager lets the code a task
+  runs record its own phases as complete events on the same lane, nested
+  inside the task's slice (the serve engine's ``serve.tick.*`` spans,
+  DESIGN.md §8); a span opened on a thread outside the pool gets a lane of
+  its own, named after the thread.
 
 A third lives with the §15 verifier:
 :class:`repro.analysis.races.RaceObserver` assigns vector clocks from
@@ -50,7 +55,8 @@ from __future__ import annotations
 import json
 import threading
 import time
-from typing import Any, Optional
+from contextlib import contextmanager
+from typing import Any, Iterator, Optional
 
 from .task import Task
 
@@ -60,7 +66,7 @@ __all__ = ["PoolObserver", "StatsObserver", "ChromeTraceObserver"]
 class PoolObserver:
     """No-op base class; subclass and override the hooks you need.
 
-    Any object with these four methods works (the protocol is duck-typed);
+    Any object with these six methods works (the protocol is duck-typed);
     inheriting just saves writing the empty ones.
     """
 
@@ -170,8 +176,9 @@ class ChromeTraceObserver(PoolObserver):
 
     Open the saved file in ``chrome://tracing`` or https://ui.perfetto.dev:
     one lane (``tid``) per worker, one complete event per task execution,
-    instant events marking steals. Timestamps are microseconds relative to
-    observer construction (the format's expected unit).
+    instant events marking steals, and the program's own :meth:`span` events.
+    Timestamps are ``time.perf_counter`` microseconds relative to observer
+    construction (the format's expected unit).
     """
 
     def __init__(self, pid: int = 1) -> None:
@@ -179,12 +186,15 @@ class ChromeTraceObserver(PoolObserver):
         self._lock = threading.Lock()
         self._starts: dict[int, float] = {}
         self._events: list[dict[str, Any]] = []
+        self._lane = threading.local()  # .tid: the worker this thread is
+        self._threads: dict[int, str] = {}  # lanes of non-worker threads
         self.pid = pid
 
     def _us(self, t: float) -> float:
         return (t - self._t0) * 1e6
 
     def on_start(self, task: Task, worker: int) -> None:
+        self._lane.tid = worker
         with self._lock:
             self._starts[id(task)] = time.perf_counter()
 
@@ -260,11 +270,56 @@ class ChromeTraceObserver(PoolObserver):
                 }
             )
 
+    @contextmanager
+    def span(self, name: str, **args: Any) -> Iterator[dict[str, Any]]:
+        """Record the ``with`` block as one complete event on the calling
+        thread's lane: a worker's own lane (learned at ``on_start``), so a
+        span opened inside a task body nests in the task's slice; any other
+        thread's lane is its ident. The block gets ``args`` to add what it
+        learns by its end; they become the event's ``args``::
+
+            >>> tracer = ChromeTraceObserver()
+            >>> with tracer.span("load", shard=3) as args:
+            ...     args["rows"] = 128
+            >>> [(e["name"], e["args"]) for e in tracer.to_trace()["traceEvents"]]
+            [('thread_name', {'name': 'MainThread'}), ('load', {'shard': 3, 'rows': 128})]
+        """
+        t0 = time.perf_counter()
+        try:
+            yield args
+        finally:
+            now = time.perf_counter()
+            tid = getattr(self._lane, "tid", None)
+            if tid is None:
+                th = threading.current_thread()
+                tid = th.ident
+                self._threads.setdefault(tid, th.name)
+            ev: dict[str, Any] = {
+                "name": name,
+                "cat": "span",
+                "ph": "X",
+                "ts": self._us(t0),
+                "dur": max(0.0, (now - t0) * 1e6),
+                "pid": self.pid,
+                "tid": tid,
+            }
+            if args:
+                ev["args"] = args
+            # one list append, atomic under the GIL, and not under the
+            # lock: a span may close inside a gc callback that fired while
+            # this very thread held the lock
+            self._events.append(ev)
+
     def to_trace(self, num_workers: Optional[int] = None) -> dict[str, Any]:
         """The trace as a dict (``{"traceEvents": [...]}`` container)."""
         with self._lock:
             events = list(self._events)
-        meta = []
+            threads = dict(self._threads)
+        meta = [
+            {"name": "thread_name", "ph": "M", "pid": self.pid, "tid": tid,
+             "args": {"name": tname}}
+            for tid, tname in threads.items()
+        ]
         if num_workers is not None:
             for i in range(num_workers):
                 meta.append(

@@ -70,15 +70,28 @@ drained run costs a plan re-arm instead of a full reset + re-wire.
 ``submit_async`` rides the same facade's asyncio bridge: an async server
 can ``tokens = await engine.submit_async(prompt, n)`` without blocking its
 event loop, or stream with ``async for tok in engine.submit(...)``.
+
+Spans (DESIGN.md §8): the engine marks its own phases as
+``jax.profiler.TraceAnnotation`` spans, so a profile of a live server
+(``jax.profiler.trace`` or ``start_server``) shows them on the device ops'
+clock — ``serve.prefill`` (args ``rid``, ``bucket``, ``resume``),
+``serve.tick`` and, inside it, ``serve.tick.join`` (``joined``: the rids),
+``serve.tick.prepare`` (``live``, ``preempted``), ``serve.tick.dispatch``,
+``serve.tick.sync`` (the host blocked on the chip) and ``serve.tick.apply``
+(``tokens``, ``retired``). With ``trace_path`` the ``serve.tick.*`` spans,
+and a ``host.gc`` span (``generation``) per garbage collection, also go to
+the engine's tracer, nested in their pool task's slice.
 """
 from __future__ import annotations
 
+import gc
 import heapq
 import itertools
 import math
 import threading
 import time
 from collections import deque
+from contextlib import contextmanager
 from dataclasses import dataclass
 from typing import Iterator, Optional, Sequence, Union
 
@@ -116,6 +129,42 @@ PREFILL_PRIORITY = -1.0  # fresh prefill / no deadline
 PREFILL_SOON = -0.5  # more than half the deadline budget consumed
 PREFILL_URGENT = 0.0  # more than three quarters consumed, or a resume
 DECODE_PRIORITY = 1.0
+
+
+@contextmanager
+def _span(tracer: Optional[ChromeTraceObserver], name: str, **args) -> Iterator[dict]:
+    """One program span, two sinks: a ``jax.profiler.TraceAnnotation`` on the
+    profiler's clock (about 1 µs when no profiler is attached), and, when a
+    tracer is given, ``tracer.span`` on the calling worker's lane. The block
+    gets a dict for the args it learns by its end."""
+    late: dict = {}
+    with jax.profiler.TraceAnnotation(name, **args) as ann:
+        if tracer is None:
+            yield late
+        else:
+            with tracer.span(name, **args) as targs:
+                yield late
+                targs.update(late)
+        if late:
+            ann.set_metadata(**late)
+
+
+class _GcSpans:
+    """``gc.callbacks`` hook: each collection, on whichever thread runs it,
+    as a ``host.gc`` span in both sinks. Collections never overlap (the
+    interpreter runs one at a time), so one open span is all it holds."""
+
+    def __init__(self, tracer: ChromeTraceObserver) -> None:
+        self.tracer = tracer
+        self._open = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._open = _span(self.tracer, "host.gc", generation=info["generation"])
+            self._open.__enter__()
+        elif self._open is not None:
+            span, self._open = self._open, None
+            span.__exit__(None, None, None)
 
 
 class QueueFull(RuntimeError):
@@ -171,9 +220,15 @@ class RequestHandle:
     failure and end cleanly on completion.
 
     Latency marks (``time.monotonic`` seconds): ``submit_t`` at submission,
-    ``first_token_t`` when the first token is delivered (TTFT =
+    ``prefill_start_t`` when the body of its first prefill starts,
+    ``prefill_done_t`` when that prefill's result is handed to the join
+    queue, ``first_token_t`` when the first token is delivered (TTFT =
     ``first_token_t - submit_t``, also exposed as ``.ttft``), and
-    ``token_times`` for every delivered token (inter-token gaps).
+    ``token_times`` for every delivered token (inter-token gaps). The
+    prefill marks come from the first prefill only (a resume after
+    preemption leaves them), and stay None for a request that never got
+    that far; for a request that ran, queue wait, prefill and the wait to
+    join the decode batch (which delivers the first token) sum to its TTFT.
     """
 
     def __init__(
@@ -188,6 +243,8 @@ class RequestHandle:
         self.deadline = deadline
         self.truncated = False
         self.submit_t = time.monotonic()
+        self.prefill_start_t: Optional[float] = None
+        self.prefill_done_t: Optional[float] = None
         self.first_token_t: Optional[float] = None
         self.token_times: list[float] = []
         self._cv = threading.Condition()
@@ -393,10 +450,19 @@ class ServeEngine:
         When set, a :class:`~repro.core.ChromeTraceObserver` is attached to
         the pool for the engine's lifetime and the trace (every prefill
         task, decode tick and steal, per worker lane) is written there on
-        ``close()`` — load it in ``chrome://tracing``. Exposed as
-        ``self.tracer`` for mid-run snapshots (``tracer.to_trace()``). On a
-        shared pool the trace includes the other users' tasks too, which is
-        usually what you want when diagnosing interference.
+        ``close()`` — load it in ``chrome://tracing``. Inside each
+        ``decode-tick`` slice it holds the tick's phases, in order:
+        ``serve.tick.join`` (args ``joined``), ``serve.tick.prepare``
+        (``live``, ``preempted``), ``serve.tick.dispatch``,
+        ``serve.tick.sync`` and ``serve.tick.apply`` (``tokens``,
+        ``retired``); and a ``host.gc`` span (``generation``) for every
+        garbage collection, from a ``gc.callbacks`` hook installed until
+        ``close()``. Exposed as ``self.tracer`` for mid-run snapshots
+        (``tracer.to_trace()``). On a shared pool the trace includes the
+        other users' tasks too, which is usually what you want when
+        diagnosing interference. Without it the engine records no tracer
+        events; its ``TraceAnnotation`` spans (module docs) still reach a
+        running ``jax.profiler`` trace.
     """
 
     def __init__(
@@ -517,6 +583,10 @@ class ServeEngine:
         self._tokens_out = 0
         self._ticks = 0
         self._occupancy_sum = 0
+        self._gc_spans: Optional[_GcSpans] = None
+        if self.tracer is not None:
+            self._gc_spans = _GcSpans(self.tracer)
+            gc.callbacks.append(self._gc_spans)
 
     # -- client API -----------------------------------------------------------
 
@@ -668,6 +738,7 @@ class ServeEngine:
                 self._idle.wait_for(lambda: not self._tick_live, 60.0)
         if self.tracer is not None:
             tracer, self.tracer = self.tracer, None  # idempotent close
+            gc.callbacks.remove(self._gc_spans)
             self.pool.remove_observer(tracer)
             tracer.save(self._trace_path, num_workers=self.pool.num_threads)
         if self._own_pool:
@@ -784,32 +855,37 @@ class ServeEngine:
         exactly once per task — never for a retried attempt.
         """
         handle, req = p.handle, p.req
-        if not p.tokens and p.deadline is not None and time.monotonic() >= p.deadline:
-            raise DeadlineExceeded(
-                f"request {handle.rid} missed its {req.deadline:.3f}s deadline "
-                "before prefill started"
+        if handle.prefill_start_t is None:
+            handle.prefill_start_t = time.monotonic()
+        # the pool's prefill:<rid> slice covers this body in the tracer
+        with _span(None, "serve.prefill", rid=handle.rid, resume=bool(p.tokens)) as late:
+            if not p.tokens and p.deadline is not None and time.monotonic() >= p.deadline:
+                raise DeadlineExceeded(
+                    f"request {handle.rid} missed its {req.deadline:.3f}s deadline "
+                    "before prefill started"
+                )
+            if p.tokens:
+                # resume a preempted sequence: re-prefill prompt + generated
+                # prefix except the last token (it is the next decode feed).
+                # Exact length, no bucketing — the length is feed_index and
+                # is < max_len by the retire invariant.
+                seq_toks = np.concatenate(
+                    [req.prompt, np.asarray(p.tokens[:-1], np.int32)]
+                )
+                plen = pad = int(seq_toks.size)
+            else:
+                seq_toks = req.prompt
+                plen = int(req.prompt.size)
+                pad = self._bucket(plen)
+            late["bucket"] = pad
+            toks = np.zeros((1, pad), np.int32)
+            toks[0, :plen] = seq_toks
+            logits, cache = self._prefill_jit(
+                self.params,
+                {"tokens": jnp.asarray(toks)},
+                last_pos=jnp.asarray(plen - 1, jnp.int32),
             )
-        if p.tokens:
-            # resume a preempted sequence: re-prefill prompt + generated
-            # prefix except the last token (it is the next decode feed).
-            # Exact length, no bucketing — the length is feed_index and
-            # is < max_len by the retire invariant.
-            seq_toks = np.concatenate(
-                [req.prompt, np.asarray(p.tokens[:-1], np.int32)]
-            )
-            plen = pad = int(seq_toks.size)
-        else:
-            seq_toks = req.prompt
-            plen = int(req.prompt.size)
-            pad = self._bucket(plen)
-        toks = np.zeros((1, pad), np.int32)
-        toks[0, :plen] = seq_toks
-        logits, cache = self._prefill_jit(
-            self.params,
-            {"tokens": jnp.asarray(toks)},
-            last_pos=jnp.asarray(plen - 1, jnp.int32),
-        )
-        p.joined = (cache, int(jnp.argmax(logits[0, -1])), pad)
+            p.joined = (cache, int(jnp.argmax(logits[0, -1])), pad)
 
     def _prefill_done(self, p: _Pending, task: Task) -> None:
         """Terminal prefill outcome (task ``on_done``): deliver failure or
@@ -852,6 +928,8 @@ class ServeEngine:
                 exc = self._broken
             else:
                 p.stage = "join"
+                if handle.prefill_done_t is None:
+                    handle.prefill_done_t = time.monotonic()
                 self._joinq.append((p, cache, first, pad))
                 self._schedule_tick_locked()
                 return
@@ -888,7 +966,9 @@ class ServeEngine:
 
     def _tick(self) -> None:
         try:
-            self._tick_body()
+            # the pool's decode-tick slice covers this body in the tracer
+            with _span(None, "serve.tick"):
+                self._tick_body()
         except BaseException as exc:  # noqa: BLE001 - fail every request and
             # brick the engine: the donated kv buffers may be invalid now
             with self._lock:
@@ -930,97 +1010,109 @@ class ServeEngine:
         self._preemptions += 1
 
     def _tick_body(self) -> None:
+        tracer = self.tracer  # one sink for the whole tick, even across close()
         # 1. join freshly prefilled sequences into free slots (paged: the
         #    join claims pages for the prefilled prompt only)
-        with self._lock:
-            joins = []
-            while self._joinq:
-                p, cache, first, pad = self._joinq[0]
-                slot = self.kv.alloc(self.kv.pages_for(pad))
-                if slot is None:  # lookahead prefills wait for slot/pages
-                    break
-                self._joinq.popleft()
-                self._pending_by_rid.pop(p.handle.rid, None)
-                p.stage = "active"
-                if p.tokens:  # resumed sequence: prefix already delivered
-                    seq = _Seq(
-                        p,
-                        list(p.tokens),
-                        p.handle.prompt_len + len(p.tokens) - 1,
-                        p.req.max_new_tokens - len(p.tokens),
-                        slot,
-                    )
-                else:
-                    seq = _Seq(p, [first], p.handle.prompt_len, p.req.max_new_tokens - 1, slot)
-                    self._tokens_out += 1  # the prefill-produced first token
-                    p.handle._push(first)
-                self._active[slot] = seq
-                joins.append((slot, cache, pad))
-        for slot, cache, pad in joins:
-            self.kv.write(slot, cache, pad)  # tick chain serializes buffers
+        with _span(tracer, "serve.tick.join") as late:
+            with self._lock:
+                joins = []
+                while self._joinq:
+                    p, cache, first, pad = self._joinq[0]
+                    slot = self.kv.alloc(self.kv.pages_for(pad))
+                    if slot is None:  # lookahead prefills wait for slot/pages
+                        break
+                    self._joinq.popleft()
+                    self._pending_by_rid.pop(p.handle.rid, None)
+                    p.stage = "active"
+                    if p.tokens:  # resumed sequence: prefix already delivered
+                        seq = _Seq(
+                            p,
+                            list(p.tokens),
+                            p.handle.prompt_len + len(p.tokens) - 1,
+                            p.req.max_new_tokens - len(p.tokens),
+                            slot,
+                        )
+                    else:
+                        seq = _Seq(p, [first], p.handle.prompt_len, p.req.max_new_tokens - 1, slot)
+                        self._tokens_out += 1  # the prefill-produced first token
+                        p.handle._push(first)
+                    self._active[slot] = seq
+                    joins.append((slot, cache, pad, p.handle.rid))
+            for slot, cache, pad, _rid in joins:
+                self.kv.write(slot, cache, pad)  # tick chain serializes buffers
+            late["joined"] = [j[3] for j in joins]
 
         retired: list = []
-        with self._lock:
-            self._retire_locked(retired)  # max_new_tokens == 1 finishes at join
-            # 1b. back every lane's write position with a physical page;
-            #     on page pressure preempt the youngest resident (oldest
-            #     sequences grow first, so the victim order is stable)
-            for seq in sorted(self._active.values(), key=lambda s: s.p.order):
-                while seq.slot in self._active and not self.kv.grow_to(
-                    seq.slot, seq.feed_index + 1
-                ):
-                    victim = max(self._active.values(), key=lambda s: s.p.order)
-                    self._preempt_locked(victim)
-            if not self._active:
-                # nothing to decode this pass; the condition task loops if
-                # the join queue refilled, else the cycle drains
-                self._pump_locked()
-                self._idle.notify_all()
-                self._resolve(retired)
-                return
-            tok_np = np.zeros((self.kv.max_slots, 1, 1), np.int32)
-            idx_np = np.zeros((self.kv.max_slots,), np.int32)
-            feeds: dict[int, int] = {}
-            for slot, seq in self._active.items():
-                tok_np[slot, 0, 0] = seq.tokens[-1]
-                idx_np[slot] = seq.feed_index
-                feeds[slot] = seq.feed_index
-            self._ticks += 1
-            self._occupancy_sum += len(self._active)
+        with _span(tracer, "serve.tick.prepare") as late:
+            with self._lock:
+                self._retire_locked(retired)  # max_new_tokens == 1 finishes at join
+                # 1b. back every lane's write position with a physical page;
+                #     on page pressure preempt the youngest resident (oldest
+                #     sequences grow first, so the victim order is stable)
+                preemptions = self._preemptions
+                for seq in sorted(self._active.values(), key=lambda s: s.p.order):
+                    while seq.slot in self._active and not self.kv.grow_to(
+                        seq.slot, seq.feed_index + 1
+                    ):
+                        victim = max(self._active.values(), key=lambda s: s.p.order)
+                        self._preempt_locked(victim)
+                late.update(live=len(self._active), preempted=self._preemptions - preemptions)
+                if not self._active:
+                    # nothing to decode this pass; the condition task loops if
+                    # the join queue refilled, else the cycle drains
+                    self._pump_locked()
+                    self._idle.notify_all()
+                    self._resolve(retired)
+                    return
+                tok_np = np.zeros((self.kv.max_slots, 1, 1), np.int32)
+                idx_np = np.zeros((self.kv.max_slots,), np.int32)
+                feeds: dict[int, int] = {}
+                for slot, seq in self._active.items():
+                    tok_np[slot, 0, 0] = seq.tokens[-1]
+                    idx_np[slot] = seq.feed_index
+                    feeds[slot] = seq.feed_index
+                self._ticks += 1
+                self._occupancy_sum += len(self._active)
+            if self._paged:
+                tables, dest = self.kv.tick_inputs(feeds)
 
-        # 2. one decode step over the padded slot batch (outside the lock)
-        if self._paged:
-            tables, dest = self.kv.tick_inputs(feeds)
-            next_toks, self.kv.pools = self._tick_jit(
-                self.params,
-                jnp.asarray(tok_np),
-                self.kv.pools,
-                jnp.asarray(tables),
-                jnp.asarray(dest),
-                jnp.asarray(idx_np),
-            )
-        else:
-            next_toks, self.kv.buffers = self._tick_jit(
-                self.params, jnp.asarray(tok_np), self.kv.buffers, jnp.asarray(idx_np)
-            )
-        next_np = np.asarray(next_toks)  # (slots, 1)
+        # 2. one decode step over the padded slot batch (outside the lock):
+        #    the call returns once enqueued; the sync is the host's wait
+        with _span(tracer, "serve.tick.dispatch"):
+            if self._paged:
+                next_toks, self.kv.pools = self._tick_jit(
+                    self.params,
+                    jnp.asarray(tok_np),
+                    self.kv.pools,
+                    jnp.asarray(tables),
+                    jnp.asarray(dest),
+                    jnp.asarray(idx_np),
+                )
+            else:
+                next_toks, self.kv.buffers = self._tick_jit(
+                    self.params, jnp.asarray(tok_np), self.kv.buffers, jnp.asarray(idx_np)
+                )
+        with _span(tracer, "serve.tick.sync"):
+            next_np = np.asarray(next_toks)  # (slots, 1)
 
         # 3. apply results, retire finished/evicted, admit more work
-        pushes = []
-        with self._lock:
-            for slot, seq in list(self._active.items()):
-                tok = int(next_np[slot, 0])
-                seq.tokens.append(tok)
-                seq.feed_index += 1
-                seq.remaining -= 1
-                self._tokens_out += 1
-                pushes.append((seq.handle, tok))
-            self._retire_locked(retired)
-            self._pump_locked()
-            self._idle.notify_all()  # the condition task decides the loop
-        for handle, tok in pushes:
-            handle._push(tok)
-        self._resolve(retired)
+        with _span(tracer, "serve.tick.apply") as late:
+            pushes = []
+            with self._lock:
+                for slot, seq in list(self._active.items()):
+                    tok = int(next_np[slot, 0])
+                    seq.tokens.append(tok)
+                    seq.feed_index += 1
+                    seq.remaining -= 1
+                    self._tokens_out += 1
+                    pushes.append((seq.handle, tok))
+                self._retire_locked(retired)
+                self._pump_locked()
+                self._idle.notify_all()  # the condition task decides the loop
+            for handle, tok in pushes:
+                handle._push(tok)
+            self._resolve(retired)
+            late.update(tokens=len(pushes), retired=len(retired))
 
     def _retire_locked(self, retired: list) -> None:
         for slot, seq in list(self._active.items()):

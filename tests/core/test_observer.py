@@ -231,3 +231,32 @@ def test_chrome_trace_marks_retries_and_timeouts():
     timeouts = [e for e in events if e["name"] == "timeout:wedged"]
     assert len(timeouts) == 1
     assert timeouts[0]["ph"] == "i" and timeouts[0]["cat"] == "fault"
+
+
+def test_chrome_trace_span_nests_on_the_worker_lane():
+    """``span()`` inside a task body lands on that worker's lane, inside the
+    task's slice, with the args the block added; on a thread outside the
+    pool it gets a lane of its own, named after the thread."""
+    tracer = ChromeTraceObserver()
+
+    def body():
+        with tracer.span("phase", step=1) as args:
+            args["rows"] = 8
+
+    with ThreadPool(2, observers=[tracer]) as pool:
+        g = TaskGraph("one")
+        g.add(body, name="work")
+        pool.run(g)
+        assert pool.wait_idle(10)
+    with tracer.span("outside"):
+        pass
+    events = json.loads(tracer.to_json(num_workers=2))["traceEvents"]
+    (task,) = [e for e in events if e["name"] == "work"]
+    (phase,) = [e for e in events if e["name"] == "phase"]
+    (outside,) = [e for e in events if e["name"] == "outside"]
+    assert phase["tid"] == task["tid"] and phase["cat"] == "span"
+    assert task["ts"] <= phase["ts"] and phase["ts"] + phase["dur"] <= task["ts"] + task["dur"]
+    assert phase["args"] == {"step": 1, "rows": 8}
+    assert outside["tid"] == threading.get_ident() and "args" not in outside
+    names = {e["tid"]: e["args"]["name"] for e in events if e["ph"] == "M"}
+    assert names == {0: "worker-0", 1: "worker-1", outside["tid"]: threading.current_thread().name}
