@@ -4,9 +4,13 @@
 All functions are pure; caches are dict pytrees suitable for scan-stacking.
 The einsum reference path is what the dry-run lowers; on TPU,
 ``repro.kernels.flash_attention`` replaces the core when cfg.use_kernels.
+
+A GQA decode cache may instead be a :class:`PagedKV`: the serving engine's
+page pools, read in place through the lane's page table (DESIGN.md §13).
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional, Tuple
 
 import jax
@@ -66,6 +70,59 @@ def _attend_dense(q, k, v, bias):
     w = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
     out = jnp.einsum("bkgqs,bskd->bqkgd", w, v)
     return out.reshape(B, Sq, H, v.shape[-1])
+
+
+# ---------------------------------------------------------------------------
+# paged decode view (the serving engine's KV pages, read in place)
+# ---------------------------------------------------------------------------
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class PagedKV:
+    """One decode lane's GQA cache as the serving engine's page pools.
+
+    The pools hold every lane's pages for every layer of a stack group and
+    are shared by all lanes; ``table`` and ``length`` are this lane's. A
+    decode step given this view in place of a ``{"k", "v"}`` cache attends
+    over the lane's pages where they lie and returns only the new token's
+    K/V (``{"k_new", "v_new"}``), which the engine writes into its page.
+    """
+
+    k_pages: Optional[jax.Array]  # (L, num_physical_pages, KV, page_size, Dh)
+    v_pages: Optional[jax.Array]
+    table: Optional[jax.Array]  # (pages_per_lane,) int32 physical page ids
+    length: Optional[jax.Array]  # () int32 tokens in the pages: the write index
+    layer: Optional[jax.Array] = None  # () int32 layer of L to read; None = 0
+
+    def layer_steps(self, count: int) -> "PagedKV":
+        """What a layer scan slices: the layer index alone, never the pools."""
+        return PagedKV(None, None, None, None, jnp.arange(count, dtype=jnp.int32))
+
+    def at_layer(self, step: "PagedKV") -> "PagedKV":
+        return dataclasses.replace(self, layer=step.layer)
+
+
+def paged_attend(q: jax.Array, k: jax.Array, v: jax.Array, view: PagedKV) -> jax.Array:
+    """Decode attention of one lane over its pages plus its new token.
+
+    q: (1, 1, H, Dh); k, v: (1, 1, KV, Dh), the token at write index
+    ``view.length``. On TPU the Pallas paged kernel reads the pages in
+    place; elsewhere its jnp oracle (``kernels.ref.paged_attention_ref``).
+    """
+    from repro.kernels import ops as kops
+    from repro.kernels import ref as kref
+
+    dt = view.k_pages.dtype
+    args = (
+        q[:, 0], k[:, 0].astype(dt), v[:, 0].astype(dt), view.k_pages, view.v_pages,
+        view.table[None], view.length[None],
+        jnp.zeros((), jnp.int32) if view.layer is None else view.layer,
+    )
+    out = jax.lax.platform_dependent(
+        *args, tpu=kops.paged_attention, default=kref.paged_attention_ref
+    )
+    return out[:, None]
 
 
 # ---------------------------------------------------------------------------
@@ -154,7 +211,12 @@ def gqa_attention(
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
 
-    if cache is not None:
+    if isinstance(cache, PagedKV):
+        # decode over the engine's pages, read in place; a paged cache is
+        # never a ring, so any window covers the whole cache
+        out = paged_attend(q, k, v, cache)
+        new_cache = {"k_new": k, "v_new": v}
+    elif cache is not None:
         Sk = cache["k"].shape[1]
         if "pos" in cache:  # ring buffer (S must be 1)
             slot = jnp.mod(cache_index, Sk)

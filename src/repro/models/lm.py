@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .attention import PagedKV
 from .blocks import StackedAlloc, block_apply, block_cache_shape, block_params, _norm, _norm_params
 from .common import Alloc, DTYPES
 
@@ -90,6 +91,8 @@ def _merge_decode_cache(cache_in, emitted, index):
     dus = jax.lax.dynamic_update_slice_in_dim
 
     def merge(node_in, node_em):
+        if isinstance(node_in, PagedKV):
+            return node_em  # the new token's K/V: its page is written in place
         if isinstance(node_em, dict):
             if "k_new" in node_em:
                 Sk = node_in["k"].shape[-3]
@@ -133,6 +136,27 @@ def _merge_decode_cache(cache_in, emitted, index):
         return node_em  # full replacement (SSM state / conv stream)
 
     return merge(cache_in, emitted)
+
+
+def _is_paged(node) -> bool:
+    return isinstance(node, PagedKV)
+
+
+def _scan_slices(cache: dict, count: int) -> dict:
+    """The part of a stack group's cache a layer scan slices: a paged view's
+    pools stay whole (each layer reads its own through the layer index), so
+    the scan carries only that index."""
+    return jax.tree.map(
+        lambda n: n.layer_steps(count) if _is_paged(n) else n, cache, is_leaf=_is_paged
+    )
+
+
+def _layer_cache(step: dict, cache: dict) -> dict:
+    """One layer's cache inside the scan: its slices, and the whole paged
+    views at that layer."""
+    return jax.tree.map(
+        lambda s, c: c.at_layer(s) if _is_paged(s) else s, step, cache, is_leaf=_is_paged
+    )
 
 
 def stack_apply(
@@ -205,6 +229,8 @@ def stack_apply(
 
             def body(carry, xs):
                 params, cache = xs
+                if cache is not None:
+                    cache = _layer_cache(cache, cache_in)
                 xx, _ = carry
                 xx, nc, aux = run_block(params, cache, xx)
                 xx = constrain(xx)
@@ -223,7 +249,8 @@ def stack_apply(
                 )
             else:
                 (x, _), (ncs, auxs) = jax.lax.scan(
-                    scan_body, (x, None), (gp, cache_in), unroll=unroll
+                    scan_body, (x, None), (gp, _scan_slices(cache_in, grp.count)),
+                    unroll=unroll,
                 )
             total_aux = total_aux + jnp.sum(auxs)
             if mode == "decode":

@@ -30,10 +30,12 @@ running to the longest member. One tick is one jitted
 sequences of different lengths share one decode computation.
 
 KV storage defaults to the **paged** layout (:class:`~repro.serve.kv.
-PagedKVCache`): each tick gathers the resident sequences' pages into the
-logical slot batch, decodes, and scatters back only the single page each
-lane wrote. Admission holds pages for the prefilled prompt only; decode
-growth claims pages one at a time, and on page pressure the engine
+PagedKVCache`): each tick's attention reads the resident sequences' K/V
+pages in place through the page table (a Pallas kernel on TPU), and each
+lane's new token is written into its page; MLA latents are still gathered
+into the logical slot batch and their touched page scattered back.
+Admission holds pages for the prefilled prompt only; decode growth claims
+pages one at a time, and on page pressure the engine
 **preempts the youngest resident** — its pages are freed and the request
 re-enters the admit queue (at its original deadline/arrival key) to resume
 later by re-prefilling its prompt + generated prefix. Preemption moves
@@ -532,9 +534,12 @@ class ServeEngine:
             kv = self.kv
 
             def _ptick(p, tok, pools, tables, dest, idx):
-                caches = kv.gather(pools, tables)
-                toks, upd = jax.vmap(_step, in_axes=(None, 0, 0, 0))(p, tok, caches, idx)
-                return toks, kv.scatter(pools, upd, dest, idx)
+                def lane(tok, part, table, i):
+                    return _step(p, tok, kv.lane_view(pools, part, table, i), i)
+
+                parts = kv.lane_caches(pools, tables)
+                toks, upd = jax.vmap(lane)(tok, parts, tables, idx)
+                return toks, kv.commit(pools, upd, dest, idx)
 
             self._tick_jit = jax.jit(_ptick, donate_argnums=(2,))
         elif kv_layout == "flat":

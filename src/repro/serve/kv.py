@@ -25,15 +25,16 @@ over the slot axis with a per-slot write index.
 
 :class:`PagedKVCache` (DESIGN.md §13) replaces the flat per-slot layout
 with a pool of fixed-size *pages*: every growable leaf (GQA append K/V, MLA
-latents) is stored as ``(num_pages, ..., page_size, ...)`` with a free-list
-of physical page ids and a per-slot page table; fixed-size leaves (SSM
-state, sliding-window rings, static encoder K/V) stay slot-indexed exactly
-as in the flat cache. Prefill installs only the pages a prompt actually
-covers (O(pages touched), not O(max_len)), growth is appending one page id
-to a table row, and the decode tick reads through a gather that
-reassembles each slot's logical cache from its pages — bit-identical to
-the flat layout because unmapped table entries point at a reserved
-always-zero page.
+latents) is stored in pages, with a free-list of physical page ids and a
+per-slot page table; fixed-size leaves (SSM state, sliding-window rings,
+static encoder K/V) stay slot-indexed exactly as in the flat cache. Prefill
+installs only the pages a prompt actually covers (O(pages touched), not
+O(max_len)), and growth is appending one page id to a table row. The decode
+tick reads GQA K/V in place, through the page table
+(:class:`~repro.models.attention.PagedKV`), and writes each lane's new token
+into its page; MLA latents are still reassembled into each slot's logical
+cache by a gather — bit-identical to the flat layout because unmapped
+table entries point at a reserved always-zero page.
 """
 from __future__ import annotations
 
@@ -319,10 +320,11 @@ class SlotKVCache:
 class _LeafSpec:
     """Per-leaf storage classification for the paged layout.
 
-    ``kind`` is ``"page"`` for seq-growable leaves (GQA append K/V, MLA
-    latents) and ``"slot"`` for fixed-size leaves (SSM state, conv streams,
-    ring K/V/pos, static cross-attention K/V). ``ax`` is the sequence axis
-    inside the batch-1 slot layout for page leaves.
+    ``kind`` is ``"kv"`` for GQA append K/V (paged, read in place by the
+    decode tick), ``"page"`` for MLA latents (paged, gathered into a logical
+    cache each tick) and ``"slot"`` for fixed-size leaves (SSM state, conv
+    streams, ring K/V/pos, static cross-attention K/V). ``ax`` is the
+    sequence axis inside the batch-1 slot layout for paged leaves.
     """
 
     __slots__ = ("kind", "ax")
@@ -343,7 +345,7 @@ def _leaf_specs(shapes: dict) -> Any:
         if isinstance(node, dict):
             if not static and _is_gqa(node) and "pos" not in node:
                 ax = node["k"].ndim - 3  # (..., B, S, KV, Dh)
-                return {k: _LeafSpec("page", ax) for k in node}
+                return {k: _LeafSpec("kv", ax) for k in node}
             if not static and _is_mla(node):
                 ax = node["ckv"].ndim - 2  # (..., B, S, L)
                 return {k: _LeafSpec("page", ax) for k in node}
@@ -353,12 +355,34 @@ def _leaf_specs(shapes: dict) -> Any:
     return walk(shapes)
 
 
+def _is_spec(node: Any) -> bool:
+    return isinstance(node, _LeafSpec)
+
+
+def _is_kv_node(spec: Any) -> bool:
+    """A ``{"k", "v"}`` node read in place (one PagedKV view)."""
+    return isinstance(spec, dict) and bool(spec) and all(
+        _is_spec(s) and s.kind == "kv" for s in spec.values()
+    )
+
+
+def _layers(shape: tuple, ax: int) -> int:
+    """Stacked layers of a GQA leaf: everything before its sequence axis
+    (the scan-stacked layer axis, if any, and the batch-1 axis)."""
+    return math.prod(shape[:ax])
+
+
 class PagedKVCache:
     """Block-pooled KV cache: fixed-size pages, per-slot page tables.
 
     Storage layout (DESIGN.md §13):
 
-    * every *growable* cache leaf lives in a page pool of shape
+    * GQA append K/V live in a page pool of shape
+      ``(layers, RESERVED + num_pages, kv_heads, page_size, head_dim)``:
+      one page of one layer is contiguous, as the paged decode kernel reads
+      it (``layers`` is the leaf's stacked layer count, 1 for an unstacked
+      layer);
+    * MLA latents live in a page pool of shape
       ``(RESERVED + num_pages, ..., page_size, ...)`` where the sequence
       axis of the batch-1 slot layout is replaced by ``page_size`` and the
       physical page id leads;
@@ -377,11 +401,13 @@ class PagedKVCache:
     pages a prefill actually covers; ``grow_to`` appends page ids to a table
     row; ``free`` returns the row's pages. All O(pages touched).
 
-    ``gather``/``scatter`` are pure functions traced inside the engine's
-    decode-tick jit: gather reassembles each slot's logical ``max_len``
-    cache from its pages (unmapped tail → zero page), scatter writes back
-    the single page containing each lane's write index (inactive lanes →
-    scratch page).
+    ``lane_caches``/``lane_view``/``commit`` are pure functions traced
+    inside the engine's decode-tick jit: a lane reads GQA K/V through a
+    :class:`~repro.models.attention.PagedKV` view of the pools and its table
+    row, MLA latents through ``gather`` (each slot's logical ``max_len``
+    cache reassembled from its pages, unmapped tail → zero page); commit
+    writes each lane's new GQA token into its page and the single MLA page
+    containing its write index (inactive lanes → scratch page).
 
     Thread safety matches :class:`SlotKVCache`: page/slot accounting is
     lock-protected; ``write`` and the decode tick mutate ``pools`` and must
@@ -418,6 +444,9 @@ class PagedKVCache:
 
         self._slot_shapes = model.cache_shapes(1, max_len)
         self._spec_tree = _leaf_specs(self._slot_shapes)
+        # how the decode tick reads each paged leaf: in place or gathered
+        kinds = [s.kind for s in jax.tree.leaves(self._spec_tree, is_leaf=_is_spec)]
+        self._leaves = {k: kinds.count(k) for k in ("kv", "page")}
         rings: list = []
         _ring_modulus(self._slot_shapes, rings)
         self._ring_w = rings[0] if rings else None
@@ -428,6 +457,8 @@ class PagedKVCache:
             if spec.kind == "slot":
                 return jnp.zeros((max_slots, *s.shape), s.dtype)
             shp = s.shape
+            if spec.kind == "kv":  # (..., 1, S, KV, Dh)
+                return jnp.zeros((_layers(shp, spec.ax), nphys, shp[-2], ps, shp[-1]), s.dtype)
             return jnp.zeros(
                 (nphys, *shp[: spec.ax], ps, *shp[spec.ax + 1 :]), s.dtype
             )
@@ -457,6 +488,9 @@ class PagedKVCache:
                 if spec.kind == "slot":
                     return pool.at[slot].set(leaf)
                 shp = leaf.shape
+                if spec.kind == "kv":
+                    r = leaf.reshape(_layers(shp, spec.ax), npg, ps, *shp[-2:])
+                    return pool.at[:, page_ids].set(r.transpose(0, 1, 3, 2, 4))
                 r = leaf.reshape(*shp[: spec.ax], npg, ps, *shp[spec.ax + 1 :])
                 return pool.at[page_ids].set(jnp.moveaxis(r, spec.ax, 0))
 
@@ -583,18 +617,26 @@ class PagedKVCache:
             self.pools, cache, page_ids, jnp.asarray(slot, jnp.int32), prefill_len
         )
 
-    def gather(self, pools, tables: jax.Array):
+    def gather(self, pools, tables: jax.Array, *, in_place: bool = True):
         """Reassemble the ``(max_slots, ...)`` logical cache tree from pages.
 
         Pure/traceable; ``tables`` is the device copy of the page table.
         Unmapped entries point at the zero page, so the result is
-        bit-identical to the flat slot layout.
+        bit-identical to the flat slot layout. With ``in_place=False`` the
+        leaves the decode tick reads in place are left out (None).
         """
         ps = self.page_size
 
-        def g(spec: _LeafSpec, pool):
+        def g(spec: _LeafSpec, pool, shape):
             if spec.kind == "slot":
                 return pool
+            if spec.kind == "kv":
+                if not in_place:
+                    return None
+                pages = jnp.moveaxis(pool[:, tables], 0, 1)  # (slots, layers, P, KV, page, Dh)
+                pages = pages.swapaxes(-3, -2)  # (slots, layers, P, page, KV, Dh)
+                n = pages.shape[0]
+                return pages.reshape(n, *shape.shape[: spec.ax], -1, *shape.shape[-2:])
             pages = pool[tables]  # (slots, P, *pre, page, *post)
             pages = jnp.moveaxis(pages, 1, 1 + spec.ax)  # (slots, *pre, P, page, *post)
             shp = pages.shape
@@ -602,31 +644,71 @@ class PagedKVCache:
                 *shp[: 1 + spec.ax], shp[1 + spec.ax] * ps, *shp[3 + spec.ax :]
             )
 
-        return jax.tree.map(g, self._spec_tree, pools)
+        return jax.tree.map(g, self._spec_tree, pools, self._slot_shapes)
 
-    def scatter(self, pools, updated, dest_ids: jax.Array, idx: jax.Array):
-        """Write each lane's touched page back into the pools.
+    def lane_caches(self, pools, tables: jax.Array):
+        """What the decode tick maps over its lanes, slots leading: slot
+        leaves whole, MLA latents gathered from their pages, and nothing for
+        the GQA K/V that each lane reads in place (:meth:`lane_view`)."""
+        return self.gather(pools, tables, in_place=False)
 
-        Pure/traceable. ``updated`` is the decode-step output cache tree in
-        the logical ``(max_slots, ...)`` layout; a decode step only writes
-        position ``idx[slot]``, so the single page containing it is
-        extracted per lane and scattered to physical page ``dest_ids[slot]``
-        (the scratch page for inactive lanes). Fixed-size leaves are
-        replaced wholesale, exactly like the flat layout.
+    def lane_view(self, pools, lane: Any, table: jax.Array, length: jax.Array):
+        """One lane's cache tree for ``Model.decode_step``: its entries of
+        :meth:`lane_caches`, and for each GQA K/V node a
+        :class:`~repro.models.attention.PagedKV` over the whole pools with
+        this lane's table row and write index."""
+        from repro.models.attention import PagedKV
+
+        def walk(spec, pool, part):
+            if _is_kv_node(spec):
+                return PagedKV(pool["k"], pool["v"], table, length)
+            if isinstance(spec, dict):
+                return {k: walk(spec[k], pool[k], part[k]) for k in spec}
+            return part
+
+        return walk(self._spec_tree, pools, lane)
+
+    def commit(self, pools, updated, dest_ids: jax.Array, idx: jax.Array):
+        """Write a decode tick's results into the pools.
+
+        Pure/traceable; ``updated`` is the tick's output cache tree, lanes
+        leading, and a decode step only writes position ``idx[slot]``. A
+        GQA K/V node holds just that token (``k_new``/``v_new``, every
+        layer), written at its offset in physical page ``dest_ids[slot]``;
+        an MLA leaf holds its whole logical cache, of which the single page
+        containing the position goes to ``dest_ids[slot]``. Inactive lanes
+        write to the scratch page. Fixed-size leaves are replaced wholesale,
+        exactly like the flat layout.
         """
         ps = self.page_size
-        start = (idx // ps) * ps
+        off = idx % ps
+        start = idx - off
 
-        def s(spec: _LeafSpec, pool, upd):
-            if spec.kind == "slot":
-                return upd
+        def token(pool, new):  # pool (layers, pages, KV, page, Dh)
+            L, _, KV, _, Dh = pool.shape
+            vals = new.reshape(-1, L, 1, KV, 1, Dh).astype(pool.dtype)
+            # one in-place update per lane: a scatter over the page and
+            # offset axes makes XLA re-lay the whole pool out around it
+            zero = jnp.zeros((), jnp.int32)
+            for lane in range(vals.shape[0]):
+                at = (zero, dest_ids[lane], zero, off[lane], zero)
+                pool = jax.lax.dynamic_update_slice(pool, vals[lane], at)
+            return pool
 
+        def page(spec, pool, upd):
             def one(u, st):
                 return jax.lax.dynamic_slice_in_dim(u, st, ps, axis=spec.ax)
 
             return pool.at[dest_ids].set(jax.vmap(one)(upd, start))
 
-        return jax.tree.map(s, self._spec_tree, pools, updated)
+        def walk(spec, pool, upd):
+            if _is_kv_node(spec):
+                return {"k": token(pool["k"], upd["k_new"]), "v": token(pool["v"], upd["v_new"])}
+            if isinstance(spec, dict):
+                return {k: walk(spec[k], pool[k], upd[k]) for k in spec}
+            return upd if spec.kind == "slot" else page(spec, pool, upd)
+
+        return walk(self._spec_tree, pools, updated)
 
     def tick_inputs(self, feed: dict) -> tuple:
         """Host-side per-tick arrays: ``(page_table, dest_ids)``.
@@ -655,6 +737,10 @@ class PagedKVCache:
         capacity inside those live pages that no sequence needs (internal
         fragmentation — bounded by ``page_size - 1`` tokens per sequence,
         versus up to ``max_len - prompt`` per sequence for the flat layout).
+        ``leaves_in_place`` / ``leaves_gathered``: how many paged cache
+        leaves (a scan-stacked leaf counts once) the decode tick reads in
+        place through the page table, and how many it still gathers into a
+        logical cache every tick.
         """
         with self._lock:
             live_pages = self.num_pages - len(self._free_pages)
@@ -664,6 +750,8 @@ class PagedKVCache:
                 "max_slots": self.max_slots,
                 "live": len(self._live),
                 "free": len(self._free_slots),
+                "leaves_in_place": self._leaves["kv"],
+                "leaves_gathered": self._leaves["page"],
                 "allocs": self.allocs,
                 "frees": self.frees,
                 "evictions": self.evictions,

@@ -18,6 +18,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels.flash_attention import flash_attention_bhsd
+from repro.kernels.paged_attention import paged_decode_attention
 from repro.kernels.ssd import ssd_bshp
 from repro.models.ssm import ssm_dims
 
@@ -84,6 +85,30 @@ def test_ssd_compiles_at_mamba2_widths(one_chip):
         lambda x, dt, A, Bm, Cm: ssd_bshp(x, dt, A, Bm, Cm, chunk=cfg.ssm_chunk),
         [((1, SEQ, H, P), bf16), ((1, SEQ, H), f32), ((H,), f32),
          ((1, SEQ, N), bf16), ((1, SEQ, N), bf16)],
+        one_chip,
+    )
+    assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize(
+    "arch,lanes,max_len",
+    [("phi4-mini-3.8b", 6, 1024), ("tinyllama-1.1b", 4, 256)],
+)
+def test_paged_decode_attention_compiles_at_served_widths(one_chip, arch, lanes, max_len):
+    """The decode tick's kernel as the engine calls it: every layer's pages
+    in one pool, pages of 64 tokens (Phi-4-mini: 16 padded KV heads of 128,
+    48 query heads; the chat cell's 6 lanes of 1,024 tokens)."""
+    cfg = get_config(arch)
+    KV, Dh = cfg.kv_heads_padded, cfg.head_dim
+    G = cfg.heads_padded // KV
+    page, L = 64, cfg.num_layers
+    P = max_len // page
+    pool = (L, 2 + lanes * P, KV, page, Dh)
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    txt = _compiled_text(
+        paged_decode_attention,
+        [((lanes, KV, G, Dh), bf16), ((lanes, KV, Dh), bf16), ((lanes, KV, Dh), bf16),
+         (pool, bf16), (pool, bf16), ((lanes, P), i32), ((lanes,), i32), ((), i32)],
         one_chip,
     )
     assert "tpu_custom_call" in txt
