@@ -213,6 +213,11 @@ def _run(cell):
     del params, opt, m
     gc.collect()
 
+    compile_s = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, secs, **_kw: compile_s.append(secs)
+        if event == "/jax/core/compile/backend_compile_duration" else None
+    )
     t_ref = time.perf_counter()
     ref = reference(cell, data)
     ref_s = time.perf_counter() - t_ref
@@ -221,7 +226,7 @@ def _run(cell):
     print(
         f"info: losses={readings['losses']} ref_losses={ref['losses']} "
         f"worst={ {k: w for k, (_v, w) in got.items()} } steps_in_window={steps} "
-        f"reference_s={ref_s:.3f}",
+        f"reference_s={ref_s:.3f} reference_compile_s={sum(compile_s):.3f}",
         file=sys.stderr, flush=True,
     )
     view = SimpleNamespace(
@@ -249,26 +254,28 @@ def _run(cell):
 
 
 def _reduce(tdir):
-    """Busy time averaged over the chips, device 0's collective time and the
-    breakdown, over the traced window (first to last op on any chip)."""
+    """Busy time averaged over the chips, chip 0's collective time and the
+    breakdown, over the whole train steps of the trace on chip 0: the trace
+    starts and stops inside a step, and records those two cut short."""
     import trace_reduce
 
     tr = trace_reduce.load(trace_reduce.find_xplane(tdir))
     if not tr.devices:
         raise RuntimeError("the trace holds no TPU device plane")
-    lo = min(d.ops[0].start for d in tr.devices.values() if d.ops)
-    hi = max(max(o.end for o in d.ops) for d in tr.devices.values() if d.ops)
-    busy = [trace_reduce.busy_ns(d, lo, hi) for d in tr.devices.values()]
     dev0 = tr.devices[min(tr.devices)]
+    steps = [p for p in dev0.programs if p.name == "jit_step_fn"][1:-1]
+    if not steps:
+        raise RuntimeError("the trace holds no whole train step")
+    lo, hi = steps[0].start, steps[-1].end
+    busy = [trace_reduce.busy_ns(d, lo, hi) for d in tr.devices.values()]
     coll, exposed = trace_reduce.collective_ns(dev0, lo, hi)
     merged = trace_reduce.merge(((o.start, o.end) for o in dev0.ops), lo, hi)
     idle = sorted(trace_reduce.gaps(merged, lo, hi), key=lambda g: g[0] - g[1])[:10]
-    steps = [p.dur for p in dev0.programs if p.name == "jit_step_fn"]
     return {
         "busy_s": float(np.mean(busy)) / 1e9, "window_s": (hi - lo) / 1e9,
         "busy0_s": trace_reduce.total(merged) / 1e9, "collective_s": coll / 1e9,
-        "exposed_collective_s": exposed / 1e9, "step_device_s": sum(steps) / 1e9,
-        "steps": len(steps),
+        "exposed_collective_s": exposed / 1e9,
+        "step_device_s": sum(p.dur for p in steps) / 1e9, "steps": len(steps),
     }, {
         "device_ops": trace_reduce.top_ops(dev0, lo, hi),
         "idle_gaps": [["between steps (host)", (e - s) / 1e9] for s, e in idle],

@@ -2,15 +2,29 @@
 the result line.
 
 A cell is found by name in ``BENCHMARK.json``. Its configuration file
-(``configs/<config>.json``) names the repo's configuration, the published
-config as run, the plain reference beside it (``refs/<reference>.py``) and the
-driver that runs it (``drivers/<entry>.py``); its mix is
+(``configs/<config>.json``) holds the published config as run, at its top
+level and under its published keys, beside the harness's own keys
+(:data:`FILE_KEYS`): the repo's configuration (``repo_config``), the plain
+reference (``refs/<reference>.py``), the driver that runs it
+(``drivers/<entry>.py``) and the counts of its operations and bytes
+(``<counts>.py``, default ``counts.py``). Its mix is
 ``traffic/<traffic>.json``; each metric is read by ``metrics/<metric>.py``.
 So a new cell, mix, configuration or metric is new files and new entries,
 and no edit here.
+
+Every published key is placed, or loading fails and names it: it sets a
+``ModelConfig`` field (:data:`CONFIG_FIELDS`, or the file's own ``fields``
+map), is compared with what the repo's model runs (:data:`RUNS`: every key
+that changes the arithmetic, such as biases, a window or the router's rule),
+or is listed under ``unmapped`` with the reason it is not placed (names and
+limits). ``repo_fields``
+sets fields that no published key states (the share of experts held, KV
+padding); each is listed in ``reduced`` (reason in ``departures``) or in
+``assumed`` (reason in ``assumed_why``).
 """
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import sys
@@ -21,7 +35,15 @@ from typing import Any, Optional
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
 
-# published config key -> the repo's ModelConfig field it sets
+# a configuration file's own keys; every other key is the published config
+FILE_KEYS = frozenset({
+    "name", "source", "paper", "repo_config", "reference", "entry", "counts", "deployment",
+    "reduced", "departures", "assumed", "assumed_why", "fields", "repo_fields", "unmapped",
+    "check",
+})
+
+# published config key -> the repo's ModelConfig field it sets, where the
+# published config has the key
 CONFIG_FIELDS = {
     "num_hidden_layers": "num_layers",
     "hidden_size": "d_model",
@@ -32,6 +54,51 @@ CONFIG_FIELDS = {
     "rms_norm_eps": "norm_eps",
     "rope_theta": "rope_theta",
     "tie_word_embeddings": "tie_embeddings",
+    # mixture of experts, in the DeepSeek and HF-MoE conventions
+    "n_routed_experts": "num_experts",
+    "num_local_experts": "num_experts",
+    "num_experts": "num_experts",
+    "num_experts_per_tok": "experts_per_token",
+    "n_shared_experts": "num_shared_experts",
+    "moe_intermediate_size": "moe_d_ff",
+    "first_k_dense_replace": "first_dense_layers",
+    # latent attention (MLA)
+    **{k: k for k in (
+        "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+    )},
+}
+
+
+def _window(cfg, pub):
+    """The repo's window; none (None) runs any published window that no
+    position the model takes reaches."""
+    w = pub.get("sliding_window")
+    if cfg.window is None and w is not None and w >= pub.get("max_position_embeddings", w + 1):
+        return w
+    return cfg.window
+
+
+# published keys that change the model's arithmetic, compared with what the
+# repo's model runs given its configuration and the published config, rather
+# than set; ``head_dim`` is set by ``model_config``
+RUNS = {
+    "hidden_act": lambda cfg, pub: cfg.act,
+    "partial_rotary_factor": lambda cfg, pub: 1.0,  # RoPE on the whole head
+    "rope_scaling": lambda cfg, pub: None,  # plain RoPE at rope_theta
+    "torch_dtype": lambda cfg, pub: cfg.dtype,
+    "attention_bias": lambda cfg, pub: cfg.qkv_bias,
+    "mlp_bias": lambda cfg, pub: False,  # no MLP or expert has biases
+    "lm_head_bias": lambda cfg, pub: False,
+    "sliding_window": _window,
+    # the router: softmax over every expert, top-k, renormalised, unscaled
+    "scoring_func": lambda cfg, pub: "softmax",
+    "topk_method": lambda cfg, pub: "greedy",
+    "n_group": lambda cfg, pub: pub.get("topk_group"),  # a token reaches every group
+    "topk_group": lambda cfg, pub: pub.get("n_group"),
+    "norm_topk_prob": lambda cfg, pub: True,
+    "routed_scaling_factor": lambda cfg, pub: 1.0,
+    "moe_layer_freq": lambda cfg, pub: 1,  # experts in every layer after the dense ones
+    "seq_aux": lambda cfg, pub: False,  # the balancing loss is over the batch
 }
 
 
@@ -63,7 +130,8 @@ class Cell:
 
     @property
     def published(self) -> dict:
-        return self.config["published"]
+        """The published config as run: every key of the file but its own."""
+        return {k: v for k, v in self.config.items() if k not in FILE_KEYS}
 
     def reference(self):
         return load_module(HERE / "refs" / f"{self.config['reference']}.py")
@@ -72,26 +140,72 @@ class Cell:
         return load_module(HERE / "drivers" / f"{self.config['entry']}.py")
 
     def dims(self):
-        from counts import Dims
-
-        return Dims.from_published(self.published)
+        """Operations and bytes of the model as run, from the counts module
+        the file names under ``counts`` (``counts.py``, a dense GQA decoder,
+        where it names none)."""
+        name = self.config.get("counts", "counts")
+        if name != "counts" and not name.startswith("counts_"):
+            raise ValueError(f"counts module {name!r}: the name is 'counts' or 'counts_<family>'")
+        return importlib.import_module(name).Dims.from_published(self.published)
 
     def model_config(self):
         """The repo's configuration with the published sizes as run."""
-        from repro.configs import get_config
-
-        cfg = get_config(self.config["repo_config"])
-        pub = self.published
-        cfg = cfg.replace(
-            head_dim=pub.get("head_dim") or pub["hidden_size"] // pub["num_attention_heads"],
-            **{f: pub[k] for k, f in CONFIG_FIELDS.items()},
+        conf, pub = self.config, self.published
+        cfg = repo_config(conf["repo_config"])
+        fields = {**CONFIG_FIELDS, **conf.get("fields", {})}
+        unmapped = conf.get("unmapped", {})
+        unplaced = sorted(
+            k for k in pub
+            if k not in fields and k not in RUNS and k != "head_dim" and not unmapped.get(k)
         )
-        for k, v in self.config.get("assumed", {}).items():
+        if unplaced:
+            raise ValueError(
+                f"{conf['name']}: published keys {unplaced} are neither set, nor checked, nor "
+                "listed under 'unmapped' with a reason"
+            )
+        sets: dict = {}
+        for k, f in fields.items():
+            if k in pub:
+                if sets.get(f, pub[k]) != pub[k]:
+                    raise ValueError(f"{conf['name']}: {f} is {sets[f]!r} and {pub[k]!r}")
+                sets[f] = pub[k]
+        if pub.get("head_dim"):
+            sets["head_dim"] = pub["head_dim"]
+        elif cfg.attention != "mla":
+            sets["head_dim"] = pub["hidden_size"] // pub["num_attention_heads"]
+        repo = conf.get("repo_fields", {})
+        reasons = {
+            **{k: conf.get("departures", {}).get(k) for k in conf.get("reduced", [])},
+            **{k: conf.get("assumed_why", {}).get(k) for k in conf.get("assumed", {})},
+        }
+        unexplained = sorted(f for f in repo if not reasons.get(f) or f in sets)
+        if unexplained:
+            raise ValueError(
+                f"{conf['name']}: repo_fields {unexplained} are set by a published key, or not "
+                "listed in 'reduced' (reason in 'departures') or 'assumed' (in 'assumed_why')"
+            )
+        cfg = cfg.replace(**sets, **repo)
+        for k, v in conf.get("assumed", {}).items():
             if getattr(cfg, k, v) != v:
                 raise ValueError(f"{cfg.name}: {k} is {getattr(cfg, k)}, the file assumes {v}")
-        if cfg.act != pub["hidden_act"] or pub["partial_rotary_factor"] != 1.0:
-            raise ValueError(f"{cfg.name}: the repo's model cannot run this published config")
+        runs = {k: f(cfg, pub) for k, f in RUNS.items()}
+        off = {k: (pub[k], v) for k, v in runs.items() if pub.get(k, v) != v}
+        if off:
+            raise ValueError(
+                f"{cfg.name}: the repo's model cannot run this published config "
+                f"(published, runs): {off}"
+            )
         return cfg
+
+
+def repo_config(name: str):
+    """The repo's configuration ``name``; ``<arch>-reduced`` is that
+    architecture's reduced variant, at a size the CPU holds."""
+    from repro.configs import get_config, get_reduced
+
+    if name.endswith("-reduced"):
+        return get_reduced(name[: -len("-reduced")])
+    return get_config(name)
 
 
 def _applies(metric: dict, cell: str) -> bool:

@@ -1,5 +1,6 @@
 """Exposed collective time on chip 0, in %: the time collective ops run while
-no compute op does, over the device time of the train steps in the trace."""
+no compute op does, over the device time of chip 0's whole train steps in
+the trace."""
 
 
 def read(view):

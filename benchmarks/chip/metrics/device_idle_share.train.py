@@ -1,4 +1,5 @@
-"""Share of the traced window in which no operation ran on chip 0, in %."""
+"""Share of the span of chip 0's whole train steps in the trace (first one's
+start to last one's end) in which no operation ran on chip 0, in %."""
 
 
 def read(view):

@@ -1,8 +1,9 @@
 """Model FLOP/s utilisation of training in the traced window, in %: the
 operations the forward and backward passes need (``counts.py``: 6 per
 matmul parameter, the tied head included, plus causal attention;
-recomputation not counted) for the train steps chip 0 ran in the window,
-over the window times the chips' summed bf16 peak."""
+recomputation not counted) for the whole train steps chip 0 ran in the
+trace, over the time from the first one's start to the last one's end
+times the chips' summed bf16 peak."""
 
 
 def read(view):

@@ -213,21 +213,21 @@ def logits_at(
     use.
     """
     eps, theta = float(published["rms_norm_eps"]), float(published["rope_theta"])
-    root = root_key(seed)
-    make_layer = jax.jit(lambda l: layer_weights(root, l, dims))
+    root = root_key(seed)  # an argument of each program, so no program depends on the seed
+    make_layer = jax.jit(lambda root, l: layer_weights(root, l, dims))
     step = jax.jit(lambda x, w: _layer(x, w, eps=eps, theta=theta, quant=quant))
-    embed = jax.jit(lambda: _f32(embed_weights(root, dims), quant))()
+    embed = jax.jit(lambda root: _f32(embed_weights(root, dims), quant))(root)
     x = jnp.take(embed, jnp.asarray(tokens), axis=0)
     for layer in range(dims.layers):
-        x = step(x, make_layer(layer))
+        x = step(x, make_layer(root, layer))
 
     @jax.jit
-    def head(x, pos, embed):
+    def head(root, x, pos, embed):
         xs = jnp.take_along_axis(x, pos[..., None], axis=1)
         xs = _rms(xs, 1.0 + final_norm(root, dims).astype(jnp.float32), eps)
         return jnp.einsum("bpd,vd->bpv", xs, embed, precision=HI)
 
-    return head(x, jnp.asarray(positions), embed)
+    return head(root, x, jnp.asarray(positions), embed)
 
 
 # -- training ------------------------------------------------------------------------
@@ -238,17 +238,16 @@ MATRICES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")  # AdamW decays 
 def train_params(seed: int, dims) -> dict:
     """float32 weights: ``embed``, each layer tensor stacked over layers, and
     ``final_norm`` (norms as offsets from 1, as the serving layout keeps them)."""
-    root = root_key(seed)
 
     @jax.jit
-    def make():
+    def make(root):
         layers = jax.vmap(lambda l: layer_weights(root, l, dims))(jnp.arange(dims.layers))
         out = {k: v.astype(jnp.float32) for k, v in layers.items()}
         out["embed"] = embed_weights(root, dims).astype(jnp.float32)
         out["final_norm"] = final_norm(root, dims).astype(jnp.float32)
         return out
 
-    return make()
+    return make(root_key(seed))
 
 
 def _loss(params, tokens, targets, *, eps, theta, quant, keep, chunk):

@@ -17,7 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 ROOT = Path(__file__).resolve().parents[2]
-sys.path[:0] = [str(ROOT / "benchmarks" / "chip"), str(ROOT / "src"), str(Path(__file__).parent)]
+sys.path[:0] = [str(ROOT / "benchmarks" / "chip"), str(ROOT / "src")]
 
 import harness  # noqa: E402
 
@@ -122,9 +122,7 @@ def test_train_cell_step_fits_a_v5e_host(topo, tmp_path):
     from repro.optim.adamw import adamw_abstract_state
     from repro.runtime import Trainer, TrainerConfig
 
-    import bench_train_cell
-
-    cell = bench_train_cell.load(tmp_path)
+    cell = harness.load_cell("phi4mini.train_tp4")
     job = cell.traffic
     cfg = cell.model_config()
     mesh = make_mesh((1, job["model_parallel"]), ("data", "model"), devices=topo.devices)

@@ -26,7 +26,7 @@ SEED = 4_000_000_017
 
 def tiny_cell(seed: int = SEED):
     cell = harness.load_cell("phi4mini.chat")
-    cell.config["published"].update(
+    cell.config.update(
         hidden_size=128, intermediate_size=256, num_hidden_layers=4, num_attention_heads=4,
         num_key_value_heads=2, vocab_size=2048, head_dim=16,
     )
@@ -51,7 +51,9 @@ def test_a_sound_run_is_correct():
     assert result["failed"] == 0 and result["attempted"] == 40
     gap, limit = checks["max_logit_gap"]
     assert 0 <= gap <= limit
-    assert set(result["metrics"]) == {"ttft_p50_ms", "itl_p95_ms", "serve_tokens_per_s", "setup_s"}
+    assert set(result["metrics"]) == {
+        "ttft_p50_ms", "itl_p99_ms", "serve_tokens_per_s", "setup_s"
+    }
 
 
 def test_a_token_altered_where_it_is_produced_is_caught(monkeypatch):
@@ -122,13 +124,13 @@ def test_reference_layers_are_remade_bit_for_bit(layer):
 TRAIN_SCRIPT = r"""
 import json, sys, tempfile, time
 root = sys.argv[1]
-sys.path[:0] = [root + "/benchmarks/chip", root + "/src", root + "/tests/bench"]
-import bench_train_cell, control, harness
-tmp = tempfile.TemporaryDirectory()
-cell = bench_train_cell.load(tmp.name)
-cell.config["published"].update(
-    hidden_size=64, intermediate_size=128, num_hidden_layers=2, num_attention_heads=4,
-    num_key_value_heads=2, vocab_size=512, head_dim=16)
+sys.path[:0] = [root + "/benchmarks/chip", root + "/src"]
+import control, harness
+cell = harness.load_cell("phi4mini.train_tp4")
+# d 256: at d 64 the bf16 program's own rounding reads above the limits set on the chip
+cell.config.update(
+    hidden_size=256, intermediate_size=512, num_hidden_layers=2, num_attention_heads=4,
+    num_key_value_heads=2, vocab_size=2048, head_dim=64)
 cell.traffic.update(seq_len=64, global_batch=8)
 cell.device = {"platform": "cpu", "kind": "cpu", "count": 4}
 cell.seed, cell.seconds, cell.trace, cell.t_start = 4_000_000_017, 1.0, False, time.perf_counter()
@@ -149,7 +151,6 @@ out["unchanged"] = {"correct": result["correct"], "checks": checks}
 train.build = real
 with tempfile.TemporaryDirectory() as cell.scratch:
     out["readings"] = control.train_readings(cell, train)
-tmp.cleanup()
 print(json.dumps(out))
 """
 

@@ -1,5 +1,6 @@
 """The benchmark's yardstick on the CPU: trace reduction, operation and byte
-counts, traffic generation, and the harness's refusals."""
+counts, traffic generation, configuration files, and the harness's refusals."""
+import dataclasses
 import json
 import os
 import shutil
@@ -20,7 +21,9 @@ import peaks  # noqa: E402
 import trace_reduce  # noqa: E402
 import traffic  # noqa: E402
 
-PROBE = Path(__file__).with_name("data") / "tpu_probe.xplane.pb"
+DATA = Path(__file__).with_name("data")
+PROBE = DATA / "tpu_probe.xplane.pb"
+CONFIGS = BENCH / "configs"
 
 # A hand-made trace of one chip: a fusion runs over [1000, 5000) ns and
 # [8000, 10000), an all-reduce over [3000, 7000), inside one run of jit_step.
@@ -86,6 +89,50 @@ def test_collective_time_and_its_exposed_part(tmp_path):
     assert trace_reduce.gaps(merged, 1000, 11000) == [(7000, 8000), (10000, 11000)]
 
 
+# Three runs of the train step on one chip, the first and last cut short by
+# the trace's start and stop: ops over [1000, 2000), [2000, 3500), an
+# all-reduce over [3000, 4500), [5000, 6000) and [6000, 6500).
+TRAIN_TRACE = """
+planes {
+  id: 1
+  name: "/device:TPU:0"
+  lines { id: 1 name: "XLA Modules" timestamp_ns: 1000
+    events { metadata_id: 10 offset_ps: 0 duration_ps: 1000000 }
+    events { metadata_id: 10 offset_ps: 1000000 duration_ps: 4000000 }
+    events { metadata_id: 10 offset_ps: 5000000 duration_ps: 500000 }
+  }
+  lines { id: 2 name: "XLA Ops" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 1000000 }
+    events { metadata_id: 1 offset_ps: 1000000 duration_ps: 1500000 }
+    events { metadata_id: 2 offset_ps: 2000000 duration_ps: 1500000 }
+    events { metadata_id: 1 offset_ps: 4000000 duration_ps: 1000000 }
+    events { metadata_id: 1 offset_ps: 5000000 duration_ps: 500000 }
+  }
+  event_metadata { key: 1 value { id: 1
+    name: "%fusion.1 = bf16[8]{0} fusion(bf16[8]{0} %a), kind=kLoop" } }
+  event_metadata { key: 2 value { id: 2
+    name: "%all-reduce.3 = f32[8]{0} all-reduce(f32[8]{0} %b), replica_groups={{0,1,2,3}}" } }
+  event_metadata { key: 10 value { id: 10 name: "jit_step_fn(123)" } }
+}
+"""
+
+
+def test_train_trace_reads_the_whole_steps_alone(tmp_path):
+    from jax.profiler import ProfileData
+
+    (tmp_path / "t.xplane.pb").write_bytes(
+        ProfileData.text_proto_to_serialized_xspace(TRAIN_TRACE)
+    )
+    train = harness.load_module(BENCH / "drivers" / "train.py")
+    trace, breakdown = train._reduce(str(tmp_path))
+    # the one whole step runs [2000, 6000); the cut ones at the edges are left out
+    assert trace["steps"] == 1
+    assert trace["window_s"] == trace["step_device_s"] == pytest.approx(4e-6)
+    assert trace["busy0_s"] == pytest.approx(3.5e-6)  # [2000, 4500) and [5000, 6000)
+    assert trace["exposed_collective_s"] == pytest.approx(1e-6)  # [3500, 4500)
+    assert breakdown["idle_gaps"] == [["between steps (host)", pytest.approx(5e-7)]]
+
+
 def test_interval_union_clips_and_merges():
     assert trace_reduce.merge([(5, 9), (0, 3), (2, 4), (8, 12)], 1, 10) == [(1, 4), (5, 10)]
     assert trace_reduce.gaps([(1, 4), (5, 10)], 0, 12) == [(0, 1), (4, 5), (10, 12)]
@@ -113,9 +160,22 @@ def test_program_name_drops_the_fingerprint():
 # -- operations and bytes ----------------------------------------------------------
 
 
+def _file_cell(conf: dict) -> harness.Cell:
+    return harness.Cell(
+        name="probe", chips=1, config=conf, traffic={}, end_to_end=[], per_layer=[]
+    )
+
+
+def _conf(name: str) -> dict:
+    return json.loads((CONFIGS / f"{name}.json").read_text())
+
+
+def _dsv2() -> dict:
+    return json.loads((DATA / "deepseek-v2-shaped.json").read_text())
+
+
 def _phi4() -> counts.Dims:
-    conf = json.loads((BENCH / "configs" / "phi4-mini-3.8b.json").read_text())
-    return counts.Dims.from_published(conf["published"])
+    return _file_cell(_conf("phi4-mini-3.8b")).dims()
 
 
 def test_phi4_mini_counts_by_hand():
@@ -260,3 +320,138 @@ def test_every_cell_resolves_to_its_files():
         for m in cell.end_to_end + cell.per_layer:
             assert (BENCH / "metrics" / f"{m['name']}.py").exists(), m["name"]
         assert cell.model_config().num_layers == cell.published["num_hidden_layers"]
+
+
+# -- configuration files ------------------------------------------------------------
+
+# What both Phi-4-mini files ran as before the harness placed every published
+# key (the 32-layer file; the four-chip one differs in depth alone).
+PHI4_MODEL_CONFIG = {
+    "name": "phi4-mini-3.8b", "family": "dense", "num_layers": 32, "d_model": 3072,
+    "num_heads": 24, "num_kv_heads": 8, "d_ff": 8192, "vocab_size": 200064, "head_dim": 128,
+    "act": "silu", "qkv_bias": False, "tie_embeddings": True, "embed_scale": False,
+    "norm": "rms", "norm_eps": 1e-05, "use_rope": True, "rope_theta": 10000.0,
+    "max_seq_len": 4096, "attention": "gqa", "kv_pad_to": 16, "window": None,
+    "global_layers": (), "q_lora_rank": 0, "kv_lora_rank": 0, "qk_nope_head_dim": 0,
+    "qk_rope_head_dim": 0, "v_head_dim": 0, "num_experts": 0, "experts_per_token": 0,
+    "num_shared_experts": 0, "moe_d_ff": 0, "first_dense_layers": 0, "capacity_factor": 1.25,
+    "router_aux_loss": 0.001, "ssm_state": 0, "ssm_heads": 0, "ssm_head_dim": 64,
+    "ssm_expand": 2, "ssm_chunk": 64, "conv_kernel": 4, "encoder_layers": 0,
+    "encoder_seq": 1500, "vision_dim": 0, "num_image_tokens": 0, "sharding_rules": (),
+    "dtype": "bfloat16", "remat": "full", "loss_chunk": 512, "use_kernels": False,
+}
+PHI4_DIMS = {
+    "d": 3072, "layers": 32, "heads": 24, "kv_heads": 8, "head_dim": 128, "d_ff": 8192,
+    "vocab": 200064, "tied": True, "bytes_per_param": 2,
+}
+
+
+@pytest.mark.parametrize("name, layers", [("phi4-mini-3.8b", 32), ("phi4-mini-3.8b-tp4", 4)])
+def test_phi4_mini_files_run_as_pinned(name, layers):
+    cell = _file_cell(_conf(name))
+    assert dataclasses.asdict(cell.model_config()) == dict(PHI4_MODEL_CONFIG, num_layers=layers)
+    dims = cell.dims()
+    assert type(dims) is counts.Dims  # no "counts" key: the dense counts
+    assert dataclasses.asdict(dims) == dict(PHI4_DIMS, layers=layers)
+
+
+def test_a_deepseek_v2_shaped_file_places_every_key(tmp_path):
+    """MLA and MoE keys at reduced widths, with no head_dim and no
+    partial_rotary_factor, through ``load_cell``."""
+    from repro.models import build_model
+
+    bench = {
+        "configs": [{"name": "dsv2", "file": str(DATA / "deepseek-v2-shaped.json")}],
+        "workloads": [{"name": "dsv2.chat", "config": "dsv2", "traffic": "chat", "chips": 1}],
+        "end_to_end": [], "per_layer": [],
+    }
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    cell = harness.load_cell("dsv2.chat", bench_path=tmp_path / "BENCHMARK.json")
+    pub, cfg = cell.published, cell.model_config()
+    assert "head_dim" not in pub and "partial_rotary_factor" not in pub
+    repo = harness.repo_config("deepseek-v2-236b-reduced")
+    assert cfg.attention == "mla"
+    assert cfg.head_dim == repo.head_dim != pub["hidden_size"] // pub["num_attention_heads"]
+    placed = {
+        "q_lora_rank": "q_lora_rank", "kv_lora_rank": "kv_lora_rank",
+        "qk_nope_head_dim": "qk_nope_head_dim", "qk_rope_head_dim": "qk_rope_head_dim",
+        "v_head_dim": "v_head_dim", "num_experts": "n_routed_experts",
+        "experts_per_token": "num_experts_per_tok", "num_shared_experts": "n_shared_experts",
+        "moe_d_ff": "moe_intermediate_size", "first_dense_layers": "first_k_dense_replace",
+        "num_layers": "num_hidden_layers", "d_model": "hidden_size", "vocab_size": "vocab_size",
+        "max_seq_len": "max_position_embeddings",  # the file's own "fields" map
+    }
+    for f, k in placed.items():
+        assert getattr(cfg, f) == pub[k] != getattr(repo, f), f
+    assert cfg.capacity_factor == 2.0 != repo.capacity_factor  # "repo_fields", assumed
+    build_model(cfg).abstract_params()  # the program takes the configuration as run
+
+
+PHI4 = "phi4-mini-3.8b"
+
+
+@pytest.mark.parametrize(
+    "file, edit, named",
+    [
+        # a published key nobody places
+        (PHI4, lambda c: c.update(rope_type="yarn"), "rope_type"),
+        # a harness key misspelt reads as a published key nobody places
+        (PHI4, lambda c: c.update(reduce=c.pop("reduced")), "reduce"),
+        # listed under "unmapped" without a reason
+        (PHI4, lambda c: c["unmapped"].update(max_position_embeddings=""),
+         "max_position_embeddings"),
+        # repo_fields in neither "reduced" nor "assumed"
+        (PHI4, lambda c: c.update(repo_fields={"capacity_factor": 2.0}), "capacity_factor"),
+        # ... in "assumed" without its reason in "assumed_why"
+        (PHI4, lambda c: c.update(repo_fields={"capacity_factor": 2.0},
+                                  assumed=dict(c["assumed"], capacity_factor=2.0)),
+         "capacity_factor"),
+        # ... in "reduced" without its reason in "departures"
+        (PHI4, lambda c: c.update(repo_fields={"capacity_factor": 2.0},
+                                  reduced=c["reduced"] + ["capacity_factor"]), "capacity_factor"),
+        # ... a field that a published key sets
+        (PHI4, lambda c: c.update(repo_fields={"d_model": 64}, reduced=c["reduced"] + ["d_model"],
+                                  departures=dict(c["departures"], d_model="why")), "d_model"),
+        # published values the repo's model cannot run
+        (PHI4, lambda c: c.update(hidden_act="gelu"), "hidden_act"),
+        (PHI4, lambda c: c.update(partial_rotary_factor=0.75), "partial_rotary_factor"),
+        (PHI4, lambda c: c.update(rope_scaling={"type": "longrope"}), "rope_scaling"),
+        (PHI4, lambda c: c.update(attention_bias=True), "attention_bias"),
+        (PHI4, lambda c: c.update(mlp_bias=True), "mlp_bias"),
+        (PHI4, lambda c: c.update(lm_head_bias=True), "lm_head_bias"),
+        (PHI4, lambda c: c.update(sliding_window=4096), "sliding_window"),
+        # ... a router the repo's does not run
+        ("dsv2", lambda c: c.update(routed_scaling_factor=16), "routed_scaling_factor"),
+        ("dsv2", lambda c: c.update(topk_method="group_limited_greedy"), "topk_method"),
+        ("dsv2", lambda c: c.update(n_group=8, topk_group=3), "n_group"),
+        ("dsv2", lambda c: c.update(norm_topk_prob=False), "norm_topk_prob"),
+        ("dsv2", lambda c: c.update(scoring_func="sigmoid"), "scoring_func"),
+        ("dsv2", lambda c: c.update(moe_layer_freq=2), "moe_layer_freq"),
+        ("dsv2", lambda c: c.update(seq_aux=True), "seq_aux"),
+    ],
+)
+def test_model_config_refuses(file, edit, named):
+    conf = _dsv2() if file == "dsv2" else _conf(file)
+    edit(conf)
+    with pytest.raises(ValueError, match=named):
+        _file_cell(conf).model_config()
+
+
+def test_a_file_names_its_counts_module(tmp_path, monkeypatch):
+    (tmp_path / "counts_probe.py").write_text(
+        "class Dims:\n"
+        "    @classmethod\n"
+        "    def from_published(cls, hf):\n"
+        "        d = cls()\n"
+        "        d.experts = hf['n_routed_experts']\n"
+        "        return d\n"
+    )
+    monkeypatch.syspath_prepend(str(tmp_path))
+    conf = _dsv2()
+    try:
+        dims = _file_cell(dict(conf, counts="counts_probe")).dims()
+        assert type(dims).__module__ == "counts_probe" and dims.experts == 6
+    finally:
+        sys.modules.pop("counts_probe", None)
+    with pytest.raises(ValueError, match="counts"):
+        _file_cell(dict(conf, counts="json")).dims()
