@@ -26,7 +26,9 @@ class Dims:
     bytes_per_param: int = 2  # bf16, as served and trained
 
     @classmethod
-    def from_published(cls, hf: dict) -> "Dims":
+    def from_published(cls, hf: dict, repo_fields: dict) -> "Dims":
+        """``repo_fields``, the file's values that no published key states,
+        change nothing that a dense decoder counts."""
         heads = hf["num_attention_heads"]
         return cls(
             d=hf["hidden_size"],

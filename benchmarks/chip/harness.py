@@ -17,13 +17,17 @@ Every published key is placed, or loading fails and names it: it sets a
 map), is compared with what the repo's model runs (:data:`RUNS`: every key
 that changes the arithmetic, such as biases, a window or the router's rule),
 or is listed under ``unmapped`` with the reason it is not placed (names and
-limits). ``repo_fields``
-sets fields that no published key states (the share of experts held, KV
+limits). A key that the file's ``fields`` map places is set and not judged,
+``RUNS`` keys among them: a model whose router or RoPE the program states in
+a field of its own comes in as a file, with no edit here. A field the repo's
+configuration lacks fails loading and names the key. ``repo_fields`` sets
+fields that no published key states (the share of experts held, KV
 padding); each is listed in ``reduced`` (reason in ``departures``) or in
-``assumed`` (reason in ``assumed_why``).
+``assumed`` (reason in ``assumed_why``). A JSON list is set as a tuple.
 """
 from __future__ import annotations
 
+import dataclasses
 import importlib
 import importlib.util
 import json
@@ -142,17 +146,21 @@ class Cell:
     def dims(self):
         """Operations and bytes of the model as run, from the counts module
         the file names under ``counts`` (``counts.py``, a dense GQA decoder,
-        where it names none)."""
+        where it names none), given the published keys and the file's
+        ``repo_fields`` (the experts held, where a chip holds a share)."""
         name = self.config.get("counts", "counts")
         if name != "counts" and not name.startswith("counts_"):
             raise ValueError(f"counts module {name!r}: the name is 'counts' or 'counts_<family>'")
-        return importlib.import_module(name).Dims.from_published(self.published)
+        return importlib.import_module(name).Dims.from_published(
+            self.published, self.config.get("repo_fields", {})
+        )
 
     def model_config(self):
         """The repo's configuration with the published sizes as run."""
         conf, pub = self.config, self.published
         cfg = repo_config(conf["repo_config"])
-        fields = {**CONFIG_FIELDS, **conf.get("fields", {})}
+        mapped = conf.get("fields", {})
+        fields = {**CONFIG_FIELDS, **mapped}
         unmapped = conf.get("unmapped", {})
         unplaced = sorted(
             k for k in pub
@@ -163,9 +171,15 @@ class Cell:
                 f"{conf['name']}: published keys {unplaced} are neither set, nor checked, nor "
                 "listed under 'unmapped' with a reason"
             )
+        have = {f.name for f in dataclasses.fields(cfg)}
         sets: dict = {}
         for k, f in fields.items():
             if k in pub:
+                if f not in have:
+                    raise ValueError(
+                        f"{conf['name']}: published key {k!r} is mapped to {f!r}, which the "
+                        f"repo's configuration {type(cfg).__name__} has no field for"
+                    )
                 if sets.get(f, pub[k]) != pub[k]:
                     raise ValueError(f"{conf['name']}: {f} is {sets[f]!r} and {pub[k]!r}")
                 sets[f] = pub[k]
@@ -184,11 +198,12 @@ class Cell:
                 f"{conf['name']}: repo_fields {unexplained} are set by a published key, or not "
                 "listed in 'reduced' (reason in 'departures') or 'assumed' (in 'assumed_why')"
             )
-        cfg = cfg.replace(**sets, **repo)
+        cfg = cfg.replace(**{f: _tupled(v) for f, v in {**sets, **repo}.items()})
         for k, v in conf.get("assumed", {}).items():
+            v = _tupled(v)
             if getattr(cfg, k, v) != v:
                 raise ValueError(f"{cfg.name}: {k} is {getattr(cfg, k)}, the file assumes {v}")
-        runs = {k: f(cfg, pub) for k, f in RUNS.items()}
+        runs = {k: f(cfg, pub) for k, f in RUNS.items() if k not in mapped}
         off = {k: (pub[k], v) for k, v in runs.items() if pub.get(k, v) != v}
         if off:
             raise ValueError(
@@ -196,6 +211,11 @@ class Cell:
                 f"(published, runs): {off}"
             )
         return cfg
+
+
+def _tupled(v):
+    """A JSON list as the tuple a ``ModelConfig`` field holds, nested lists too."""
+    return tuple(_tupled(x) for x in v) if isinstance(v, list) else v
 
 
 def repo_config(name: str):

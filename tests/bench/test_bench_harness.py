@@ -7,6 +7,7 @@ import shutil
 import subprocess
 import sys
 from pathlib import Path
+from typing import Any
 
 import numpy as np
 import pytest
@@ -20,6 +21,7 @@ import harness  # noqa: E402
 import peaks  # noqa: E402
 import trace_reduce  # noqa: E402
 import traffic  # noqa: E402
+from repro.configs.base import ModelConfig  # noqa: E402
 
 DATA = Path(__file__).with_name("data")
 PROBE = DATA / "tpu_probe.xplane.pb"
@@ -172,6 +174,17 @@ def _conf(name: str) -> dict:
 
 def _dsv2() -> dict:
     return json.loads((DATA / "deepseek-v2-shaped.json").read_text())
+
+
+def _load(tmp_path, file: Path) -> harness.Cell:
+    """The file's cell through ``load_cell``, from a BENCHMARK.json of its own."""
+    bench = {
+        "configs": [{"name": "probe", "file": str(file)}],
+        "workloads": [{"name": "probe.chat", "config": "probe", "traffic": "chat", "chips": 1}],
+        "end_to_end": [], "per_layer": [],
+    }
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    return harness.load_cell("probe.chat", bench_path=tmp_path / "BENCHMARK.json")
 
 
 def _phi4() -> counts.Dims:
@@ -346,13 +359,56 @@ PHI4_DIMS = {
 }
 
 
+def _default(f: dataclasses.Field):
+    return f.default_factory() if f.default_factory is not dataclasses.MISSING else f.default
+
+
+def _off_pin(cfg, pin: dict) -> dict:
+    """Where ``cfg`` leaves ``pin``: a pinned key that is no field, a pinned
+    value that moved, or a field outside the pin off its declared default."""
+    fields = {f.name: f for f in dataclasses.fields(cfg)}
+    want = {**{k: _default(f) for k, f in fields.items()}, **pin}
+    got = {k: getattr(cfg, k) if k in fields else "no field" for k in want}
+    return {k: (got[k], v) for k, v in want.items() if got[k] != v}
+
+
 @pytest.mark.parametrize("name, layers", [("phi4-mini-3.8b", 32), ("phi4-mini-3.8b-tp4", 4)])
 def test_phi4_mini_files_run_as_pinned(name, layers):
+    """Every pinned value as loaded, and every field that the pin does not
+    name at its declared default: a field that a later model adds, with a
+    default that runs what runs today, leaves Phi-4-mini as it was."""
     cell = _file_cell(_conf(name))
-    assert dataclasses.asdict(cell.model_config()) == dict(PHI4_MODEL_CONFIG, num_layers=layers)
+    assert _off_pin(cell.model_config(), dict(PHI4_MODEL_CONFIG, num_layers=layers)) == {}
     dims = cell.dims()
     assert type(dims) is counts.Dims  # no "counts" key: the dense counts
     assert dataclasses.asdict(dims) == dict(PHI4_DIMS, layers=layers)
+
+
+def _as(cls, cfg, **kw):
+    """``cfg`` as an instance of ``cls``, a subclass with more fields."""
+    return cls(**{f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}, **kw)
+
+
+@dataclasses.dataclass(frozen=True)
+class _WithScale(ModelConfig):
+    router_scale: float = 1.0  # a field a later model adds, at what runs today
+
+
+@pytest.mark.parametrize(
+    "make, pin_edit, off",
+    [
+        (lambda c: _as(_WithScale, c), {}, set()),
+        (lambda c: _as(_WithScale, c, router_scale=16.0), {}, {"router_scale"}),
+        (lambda c: _as(_WithScale, c).replace(loss_chunk=0), {}, {"loss_chunk"}),
+        (lambda c: _as(_WithScale, c), {"rope_factor": 1.0}, {"rope_factor"}),
+    ],
+    ids=["new-field-at-default", "new-field-moved", "pinned-value-moved", "pinned-key-gone"],
+)
+def test_the_pin_admits_a_new_defaulted_field(monkeypatch, make, pin_edit, off):
+    real = harness.repo_config
+    monkeypatch.setattr(harness, "repo_config", lambda name: make(real(name)))
+    cfg = _file_cell(_conf("phi4-mini-3.8b")).model_config()
+    assert set(_off_pin(cfg, {**PHI4_MODEL_CONFIG, **pin_edit})) == off
 
 
 def test_a_deepseek_v2_shaped_file_places_every_key(tmp_path):
@@ -360,13 +416,7 @@ def test_a_deepseek_v2_shaped_file_places_every_key(tmp_path):
     partial_rotary_factor, through ``load_cell``."""
     from repro.models import build_model
 
-    bench = {
-        "configs": [{"name": "dsv2", "file": str(DATA / "deepseek-v2-shaped.json")}],
-        "workloads": [{"name": "dsv2.chat", "config": "dsv2", "traffic": "chat", "chips": 1}],
-        "end_to_end": [], "per_layer": [],
-    }
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    cell = harness.load_cell("dsv2.chat", bench_path=tmp_path / "BENCHMARK.json")
+    cell = _load(tmp_path, DATA / "deepseek-v2-shaped.json")
     pub, cfg = cell.published, cell.model_config()
     assert "head_dim" not in pub and "partial_rotary_factor" not in pub
     repo = harness.repo_config("deepseek-v2-236b-reduced")
@@ -385,6 +435,110 @@ def test_a_deepseek_v2_shaped_file_places_every_key(tmp_path):
         assert getattr(cfg, f) == pub[k] != getattr(repo, f), f
     assert cfg.capacity_factor == 2.0 != repo.capacity_factor  # "repo_fields", assumed
     build_model(cfg).abstract_params()  # the program takes the configuration as run
+
+
+# fields in which a repo configuration could state DeepSeek-V2's router and
+# RoPE, each defaulting to what the repo's model runs today
+ROUTER_AND_ROPE = {
+    "router_scoring": "softmax", "router_topk_method": "greedy", "router_groups": 1,
+    "router_topk_groups": 1, "router_renormalise": True, "router_scale": 1.0,
+    "router_seq_aux": False, "rope_scaling": None,
+}
+DSV2 = DATA / "deepseek-v2-published.json"
+
+
+def _stand_in(monkeypatch, drop: str = ""):
+    """Serve ``harness.repo_config`` a subclass of the repo's configuration
+    with the fields of :data:`ROUTER_AND_ROPE`, but ``drop``."""
+    cls = dataclasses.make_dataclass(
+        "StandIn",
+        [(f, Any, dataclasses.field(default=v)) for f, v in ROUTER_AND_ROPE.items() if f != drop],
+        bases=(ModelConfig,),
+        frozen=True,
+    )
+    real = harness.repo_config
+    monkeypatch.setattr(harness, "repo_config", lambda name: _as(cls, real(name)))
+
+
+def test_the_published_deepseek_v2_file_sets_its_router_and_rope(tmp_path, monkeypatch):
+    """The catalog's config verbatim, at every published width: its router
+    and YaRN keys arrive on the stand-in's fields as published."""
+    _stand_in(monkeypatch)
+    cell = _load(tmp_path, DSV2)
+    pub, cfg = cell.published, cell.model_config()
+    assert cell.config["reduced"] == []
+    assert sorted(cell.config["fields"].values()) == sorted(ROUTER_AND_ROPE)
+    fields = cell.config["fields"]
+    assert {f: getattr(cfg, f) for f in fields.values()} == {f: pub[k] for k, f in fields.items()}
+    assert (cfg.router_topk_method, cfg.router_groups, cfg.router_topk_groups) == (
+        "group_limited_greedy", 8, 3
+    )
+    assert (cfg.router_renormalise, cfg.router_scale, cfg.router_seq_aux) == (False, 16, True)
+    assert cfg.rope_scaling["type"] == "yarn"
+    assert (cfg.rope_scaling["factor"], cfg.rope_scaling["mscale_all_dim"]) == (40, 0.707)
+    widths = {
+        "num_layers": 60, "d_model": 5120, "num_heads": 128, "num_kv_heads": 128, "d_ff": 12288,
+        "vocab_size": 102400, "q_lora_rank": 1536, "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+        "qk_rope_head_dim": 64, "v_head_dim": 128, "num_experts": 160, "experts_per_token": 6,
+        "num_shared_experts": 2, "moe_d_ff": 1536, "first_dense_layers": 1,
+    }
+    assert {f: getattr(cfg, f) for f in widths} == widths
+
+
+@pytest.mark.parametrize("drop", sorted(ROUTER_AND_ROPE))
+def test_a_mapped_key_refuses_a_repo_config_without_its_field(tmp_path, monkeypatch, drop):
+    _stand_in(monkeypatch, drop=drop)
+    cell = _load(tmp_path, DSV2)
+    key = next(k for k, f in cell.config["fields"].items() if f == drop)
+    with pytest.raises(ValueError, match=f"{key}.*{drop}"):
+        cell.model_config()
+
+
+@pytest.mark.parametrize(
+    "key, refused",
+    [
+        ("topk_method", True), ("n_group", True), ("topk_group", True),
+        ("norm_topk_prob", True), ("routed_scaling_factor", True), ("seq_aux", True),
+        ("rope_scaling", True),
+        ("scoring_func", False),  # judged, and softmax is what the repo's router runs
+    ],
+)
+def test_a_runs_key_is_judged_only_where_the_file_does_not_map_it(
+    tmp_path, monkeypatch, key, refused
+):
+    _stand_in(monkeypatch)
+    conf = json.loads(DSV2.read_text())
+    del conf["fields"][key]
+    (tmp_path / "dsv2.json").write_text(json.dumps(conf))
+    cell = _load(tmp_path, tmp_path / "dsv2.json")
+    if refused:
+        with pytest.raises(ValueError, match=f"cannot run.*{key}"):
+            cell.model_config()
+    else:
+        assert cell.model_config().router_scoring == "softmax"  # the field's default
+
+
+@pytest.mark.parametrize(
+    "edit, field, value",
+    [
+        # a published list, mapped in "fields"
+        (lambda c: c.update(full_attention_layers=[0, 3],
+                            fields={"full_attention_layers": "global_layers"}),
+         "global_layers", (0, 3)),
+        # a nested list in "repo_fields"
+        (lambda c: c.update(repo_fields={"sharding_rules": [["mlp", "data"]]},
+                            assumed=dict(c["assumed"], sharding_rules=[["mlp", "data"]]),
+                            assumed_why=dict(c["assumed_why"], sharding_rules="why")),
+         "sharding_rules", (("mlp", "data"),)),
+    ],
+    ids=["fields", "repo_fields"],
+)
+def test_a_list_sets_a_tuple(edit, field, value):
+    conf = _conf("phi4-mini-3.8b")
+    edit(conf)
+    cfg = _file_cell(conf).model_config()
+    assert getattr(cfg, field) == value and type(getattr(cfg, field)) is tuple
+    hash(cfg)  # a frozen configuration with a list in it could not be hashed
 
 
 PHI4 = "phi4-mini-3.8b"
@@ -438,12 +592,14 @@ def test_model_config_refuses(file, edit, named):
 
 
 def test_a_file_names_its_counts_module(tmp_path, monkeypatch):
+    """The counts module gets the published keys and the file's
+    ``repo_fields``: the experts held, where a chip holds a share."""
     (tmp_path / "counts_probe.py").write_text(
         "class Dims:\n"
         "    @classmethod\n"
-        "    def from_published(cls, hf):\n"
+        "    def from_published(cls, hf, repo_fields):\n"
         "        d = cls()\n"
-        "        d.experts = hf['n_routed_experts']\n"
+        "        d.experts, d.repo_fields = hf['n_routed_experts'], repo_fields\n"
         "        return d\n"
     )
     monkeypatch.syspath_prepend(str(tmp_path))
@@ -451,7 +607,13 @@ def test_a_file_names_its_counts_module(tmp_path, monkeypatch):
     try:
         dims = _file_cell(dict(conf, counts="counts_probe")).dims()
         assert type(dims).__module__ == "counts_probe" and dims.experts == 6
+        assert dims.repo_fields == conf["repo_fields"] == {"capacity_factor": 2.0}
     finally:
         sys.modules.pop("counts_probe", None)
     with pytest.raises(ValueError, match="counts"):
         _file_cell(dict(conf, counts="json")).dims()
+
+
+def test_the_dense_counts_ignore_repo_fields():
+    pub = _file_cell(_conf("phi4-mini-3.8b")).published
+    assert counts.Dims.from_published(pub, {"kv_pad_to": 16}) == _phi4()
