@@ -163,6 +163,26 @@ def gqa_params(cfg, a: Alloc) -> dict:
     return p
 
 
+# The serving form of a GQA block (DESIGN.md §13): the Q/K/V projections held
+# head-major, (..., H, d, Dh), under keys of their own. A dot under the layer
+# scan then reads each layer's slice where it lies; from the stored
+# (..., d, H, Dh) XLA copies every layer's slice into that order first.
+HEAD_MAJOR = {"wq": "wq_hm", "wk": "wk_hm", "wv": "wv_hm"}
+
+
+def gqa_head_major(p: dict) -> dict:
+    """The head-major leaves of a stored GQA block's projections (stacked
+    layers, if any, lead; padded heads stay as they are)."""
+    return {hm: jnp.swapaxes(p[k], -3, -2) for k, hm in HEAD_MAJOR.items()}
+
+
+def _project(p: dict, name: str, x: jax.Array) -> jax.Array:
+    """``x`` (B, S, d) through projection ``name``, held stored or head-major."""
+    if HEAD_MAJOR[name] in p:
+        return jnp.einsum("bsd,hdk->bshk", x, p[HEAD_MAJOR[name]])
+    return jnp.einsum("bsd,dhk->bshk", x, p[name])
+
+
 def gqa_cache_shape(cfg, batch: int, seq: int, dtype, *, ring: bool = False) -> dict:
     KV, Dh = cfg.kv_heads_padded, cfg.head_dim
     c = {
@@ -202,9 +222,7 @@ def gqa_attention(
     slot ``cache_index % W`` and masking uses the stored absolute positions.
     """
     B, S, d = x.shape
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
+    q, k, v = (_project(p, name, x) for name in ("wq", "wk", "wv"))
     if cfg.qkv_bias:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
     if cfg.use_rope:

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .attention import PagedKV
+from .attention import HEAD_MAJOR, PagedKV, gqa_head_major
 from .blocks import StackedAlloc, block_apply, block_cache_shape, block_params, _norm, _norm_params
 from .common import Alloc, DTYPES
 
@@ -358,6 +358,26 @@ class Model:
     def logical_axes(self) -> dict:
         return self._build(Alloc("axes", dtype=self.dtype))
 
+    def serving_params(self, p: dict) -> dict:
+        """The stored tree ``p`` in the form the serving programs read.
+
+        Every decoder GQA block's ``wq``/``wk``/``wv`` are held head-major
+        instead (``attention.HEAD_MAJOR``), made by one jitted transpose of
+        the stacked leaves; every other leaf is the same array as in ``p``,
+        and ``p`` is left as it was. A tree with no GQA block comes back as
+        it is. Prefill and decode read either form; training keeps the
+        stored one (DESIGN.md §13).
+        """
+        if self.cfg.attention != "gqa":
+            return p
+        stored = {g: {k: b["attn"][k] for k in HEAD_MAJOR} for g, b in p["layers"].items()}
+        held = jax.jit(_head_major)(stored)
+        layers = {}
+        for g, b in p["layers"].items():
+            attn = {k: v for k, v in b["attn"].items() if k not in HEAD_MAJOR}
+            layers[g] = {**b, "attn": {**attn, **held[g]}}
+        return {**p, "layers": layers}
+
     # -- embedding helpers -------------------------------------------------------
 
     def _embed_tokens(self, p, tokens):
@@ -536,6 +556,11 @@ class Model:
         else:
             raise ValueError(kind)
         return out
+
+
+def _head_major(stored: dict) -> dict:
+    """Every stack group's stored projections, head-major, in one program."""
+    return {g: gqa_head_major(a) for g, a in stored.items()}
 
 
 def extend_caches(caches: dict, extra: int, *, window: Optional[int] = None) -> dict:

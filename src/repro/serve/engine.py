@@ -110,6 +110,7 @@ from repro.core import (
     TaskGraph,
     ThreadPool,
 )
+from repro.models.attention import HEAD_MAJOR
 
 from .kv import PagedKVCache, SlotKVCache
 
@@ -497,7 +498,15 @@ class ServeEngine:
                 f"no sliding window); {cfg.name} would absorb pad tokens"
             )
         self.model = model
-        self.params = params
+        # the serving form (head-major Q/K/V), made before the KV pools so
+        # its transient copy meets free memory; the caller's tree is not
+        # donated and stays usable. None: the engine is built only to
+        # lower its programs
+        self.params = None if params is None else model.serving_params(params)
+        self._head_major = sum(
+            path[-1].key in HEAD_MAJOR.values()
+            for path, _ in jax.tree_util.tree_leaves_with_path(self.params)
+        )
         self.pool = pool or ThreadPool(2, name="serve")
         self._own_pool = pool is None
         self._trace_path = trace_path
@@ -766,6 +775,8 @@ class ServeEngine:
         banded mode on first use. §13 adds ``preemptions`` (page-pressure
         evictions to the admit queue), ``rejected`` (``QueueFull``
         backpressure), ``deadline_misses`` and the live ``waiting`` depth.
+        ``params`` counts the projection leaves the engine serves head-major
+        (a scan-stacked leaf counts once; 0 where the model has no GQA).
         """
         with self._lock:
             occ = self._occupancy_sum / self._ticks if self._ticks else 0.0
@@ -784,6 +795,7 @@ class ServeEngine:
                 "tick_replays": plan.replays if plan is not None else 0,
                 "mean_occupancy": occ,
                 "kv": self.kv.stats(),
+                "params": self._head_major,
                 "pool": self.pool.stats(),
             }
 

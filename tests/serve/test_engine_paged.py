@@ -3,17 +3,21 @@
 Against the flat layout, token for token, for a dense, an MoE, an MLA and a
 hybrid configuration; one tick program for every occupancy and length; the
 KV counter in ``stats()``; and the Pallas kernel itself (interpret mode) in
-the tick in place of its oracle.
+the tick in place of its oracle. The engine serves the Q/K/V projections
+head-major: bit for bit what the stored tree computes, counted in
+``stats()["params"]``, and the caller's tree is left usable.
 """
 import functools
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_reduced
 from repro.kernels import ops, ref
 from repro.models import build_model
+from repro.models.lm import extend_caches
 from repro.serve import ServeEngine
 
 MAX_LEN, PAGE = 24, 4
@@ -93,3 +97,58 @@ def test_the_pallas_kernel_in_the_tick_streams_the_oracles_tokens(monkeypatch):
     )
     kernel, _ = _serve(model, params, prompts, budgets, "paged")
     assert kernel == oracle
+
+
+def test_the_serving_form_computes_the_stored_forms_logits_bit_for_bit():
+    cfg, model, params = _model("phi4-mini-3.8b")
+    assert cfg.kv_heads_padded > cfg.num_kv_heads  # padded heads kept as they are
+    served = model.serving_params(params)
+    attn, held = params["layers"]["s0"]["attn"], served["layers"]["s0"]["attn"]
+    assert sorted(held) == ["wk_hm", "wo", "wq_hm", "wv_hm"]
+    assert held["wq_hm"].shape == (cfg.num_layers, cfg.heads_padded, cfg.d_model, cfg.head_dim)
+    assert held["wo"] is attn["wo"] and served["embed"] is params["embed"]
+    assert served["layers"]["s0"]["mlp"] is params["layers"]["s0"]["mlp"]
+    prompt = jax.random.randint(jax.random.PRNGKey(1), (1, 9), 0, cfg.vocab_size)
+    prefill, step = jax.jit(model.prefill), jax.jit(model.decode_step)
+    logits, caches = {}, {}
+    for form, p in (("stored", params), ("serving", served)):
+        logits[form], caches[form] = prefill(p, {"tokens": prompt})
+    jax.tree.map(np.testing.assert_array_equal, caches["stored"], caches["serving"])
+    np.testing.assert_array_equal(logits["stored"], logits["serving"])
+    tok = jnp.argmax(logits["stored"][:, -1], -1)[:, None].astype(jnp.int32)
+    cache, idx = extend_caches(caches["stored"], 4), jnp.asarray(9, jnp.int32)
+    (ls, cs), (lh, ch) = step(params, tok, cache, idx), step(served, tok, cache, idx)
+    np.testing.assert_array_equal(ls, lh)
+    jax.tree.map(np.testing.assert_array_equal, cs, ch)
+
+
+def test_the_tick_streams_the_stored_forms_tokens_and_leaves_the_callers_tree(monkeypatch):
+    cfg, model, params = _model("phi4-mini-3.8b")
+    prompts, budgets = _requests(cfg, n=5, seed=2)
+    served, stats = _serve(model, params, prompts, budgets, "paged")
+    monkeypatch.setattr(model, "serving_params", lambda p: p)
+    stored, stats_stored = _serve(model, params, prompts, budgets, "paged")
+    assert served == stored
+    assert (stats["params"], stats_stored["params"]) == (3, 0)
+    # the caller's arrays were not donated: still whole and usable
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(params))
+    logits, _ = jax.jit(model.prefill)(params, {"tokens": jnp.asarray(prompts[0])[None]})
+    assert np.isfinite(np.asarray(logits, np.float32)).all()
+
+
+@pytest.mark.parametrize(
+    "arch,head_major",
+    [
+        ("phi4-mini-3.8b", 3),  # one stack group
+        ("granite-moe-1b-a400m", 3),
+        ("hymba-1.5b", 15),  # five stack groups: three global layers, two scans
+        ("deepseek-v2-236b", 0),  # MLA: the tree passes through as it is
+        ("mamba2-1.3b", 0),  # no attention
+    ],
+)
+def test_stats_count_the_head_major_projections(arch, head_major):
+    cfg, model, params = _model(arch)
+    if not head_major:
+        assert model.serving_params(params) is params
+    with ServeEngine(model, params, max_slots=2, max_len=MAX_LEN, page_size=PAGE) as engine:
+        assert engine.stats()["params"] == head_major
