@@ -50,35 +50,9 @@ sanity rails for shared runners: the regression class they exist for
 (a serialized dispatcher, a lost-wakeup stall in the slot handoff, a
 cache gone quadratic) shows up as a 10–100× blowout, not a 2× dip.
 
-The §13 serve gate (``--serve-baseline`` + ``--serve-new``, both required
-to arm it) reads ``serve_bench`` payloads and fails when any of:
-
-* a ``continuous-flat`` / ``continuous-paged`` row is missing, the paged
-  row did not complete every request, or the payload skipped output
-  verification — the gate never passes vacuously;
-* ``outputs_match_sequential_decode`` is not ``true`` (paged decode must
-  stay bit-identical to sequential decode);
-* the in-run throughput ratio ``paged_over_flat_tokens_per_s`` drops
-  below ``--serve-throughput-floor``. The floor is deliberately below the
-  ≥0.9× figure seen on dedicated hosts: on shared CI runners the flat
-  row's wall time jitters ±15%, and the regression class this arm exists
-  for (a per-tick re-gather bug, a lock on the scatter path) shows up as
-  a 2–5× collapse, not a 10% dip;
-* the fresh paged/flat p99 TTFT exceeds the committed quick baseline's
-  by more than ``--serve-ttft-threshold``× plus ``--serve-ttft-slack-ms``
-  — an admission stall or priority inversion blows p99 TTFT up by
-  seconds (queue depth × tick time), far past the envelope.
-
-Like the overhead gate, CI compares quick-vs-quick: the committed serve
-baseline is ``benchmarks/BENCH_serve_quick.json``, a ``--quick`` run
-recorded on a contended 2-vCPU host as the noise envelope (max p99 over
-several runs).
-
     PYTHONPATH=src python benchmarks/check_graph_regression.py \
         --baseline benchmarks/BENCH_graph_quick.json \
-        --new benchmarks/artifacts/BENCH_graph.json --slack-us 1.5 \
-        --serve-baseline benchmarks/BENCH_serve_quick.json \
-        --serve-new benchmarks/artifacts/BENCH_serve.json
+        --new benchmarks/artifacts/BENCH_graph.json --slack-us 1.5
 """
 from __future__ import annotations
 
@@ -112,72 +86,6 @@ def ws_rows(payload: dict, threads: int, executor: str = "ws-fast") -> dict[str,
             continue
         out[shape_prefix(row["bench"])] = row["overhead_us_per_task"]
     return out
-
-
-def serve_rows(payload: dict) -> dict[str, dict]:
-    """Map server name -> row for a serve_bench payload."""
-    return {row["server"]: row for row in payload.get("rows", []) if "server" in row}
-
-
-def serve_gate(args) -> list[str]:
-    """§13 serve gate (module docs). Returns failure labels; prints verdicts."""
-    failures: list[str] = []
-    base = json.loads(pathlib.Path(args.serve_baseline).read_text())
-    fresh_payload = json.loads(pathlib.Path(args.serve_new).read_text())
-    brows, frows = serve_rows(base), serve_rows(fresh_payload)
-
-    for name in ("continuous-flat", "continuous-paged"):
-        if name not in frows:
-            print(f"FAIL: serve: no {name} row in the fresh run")
-            failures.append(f"serve {name} (missing)")
-    if failures:
-        return failures
-
-    paged = frows["continuous-paged"]
-    requests = fresh_payload.get("meta", {}).get("requests")
-    if requests is None or paged.get("completed") != requests:
-        print(
-            f"serve              paged completed {paged.get('completed')} of "
-            f"{requests} requests  REGRESSION"
-        )
-        failures.append("serve completion")
-    if fresh_payload.get("outputs_match_sequential_decode") is not True:
-        print(
-            "serve              outputs_match_sequential_decode is "
-            f"{fresh_payload.get('outputs_match_sequential_decode')!r} "
-            "(bit-identity unverified)  REGRESSION"
-        )
-        failures.append("serve bit-identity")
-
-    ratio = fresh_payload.get("paged_over_flat_tokens_per_s")
-    if ratio is None:
-        print("FAIL: serve: no paged_over_flat_tokens_per_s in the fresh run")
-        failures.append("serve throughput (missing)")
-    else:
-        verdict = "ok" if ratio >= args.serve_throughput_floor else "REGRESSION"
-        print(
-            f"serve              paged/flat tokens/s {ratio:.3f}x "
-            f"(floor {args.serve_throughput_floor:.2f}x)  {verdict}"
-        )
-        if ratio < args.serve_throughput_floor:
-            failures.append("serve throughput")
-
-    for name in ("continuous-flat", "continuous-paged"):
-        bp = brows.get(name, {}).get("ttft_ms", {}).get("p99")
-        fp = frows[name].get("ttft_ms", {}).get("p99")
-        if bp is None or fp is None:
-            print(f"FAIL: serve: no p99 TTFT for {name} (baseline={bp}, new={fp})")
-            failures.append(f"serve {name} p99 TTFT (missing)")
-            continue
-        limit = bp * args.serve_ttft_threshold + args.serve_ttft_slack_ms
-        verdict = "ok" if fp <= limit else "REGRESSION"
-        print(
-            f"serve              {name} p99 TTFT {fp:.1f}ms vs baseline "
-            f"{bp:.1f}ms (limit {limit:.1f}ms)  {verdict}"
-        )
-        if fp > limit:
-            failures.append(f"serve {name} p99 TTFT")
-    return failures
 
 
 def socket_gate(payload: dict, args) -> list[str]:
@@ -286,39 +194,7 @@ def main() -> int:
         help="ceiling on the chain ws-socket us_per_task — the per-task TCP "
         "round-trip (generous rail; see module docs)",
     )
-    ap.add_argument(
-        "--serve-baseline",
-        default="",
-        help="committed quick serve_bench payload (BENCH_serve_quick.json); "
-        "must be paired with --serve-new to arm the §13 serve gate",
-    )
-    ap.add_argument(
-        "--serve-new",
-        default="",
-        help="freshly generated serve_bench payload (BENCH_serve.json)",
-    )
-    ap.add_argument(
-        "--serve-throughput-floor",
-        type=float,
-        default=0.7,
-        help="floor on the fresh paged/flat tokens-per-second ratio "
-        "(sanity bound for shared runners; see module docs)",
-    )
-    ap.add_argument(
-        "--serve-ttft-threshold",
-        type=float,
-        default=2.0,
-        help="max allowed ratio of fresh p99 TTFT over the serve baseline's",
-    )
-    ap.add_argument(
-        "--serve-ttft-slack-ms",
-        type=float,
-        default=75.0,
-        help="absolute noise floor on the p99 TTFT limit (ms)",
-    )
     args = ap.parse_args()
-    if bool(args.serve_baseline) != bool(args.serve_new):
-        ap.error("--serve-baseline and --serve-new must be passed together")
 
     baseline = ws_rows(json.loads(pathlib.Path(args.baseline).read_text()), args.threads)
     new_payload = json.loads(pathlib.Path(args.new).read_text())
@@ -402,18 +278,11 @@ def main() -> int:
     # §16 gate: the socket transport holds its rails inside the fresh run
     socket_failures = socket_gate(new_payload, args)
 
-    # §13 gate: paged serving must hold throughput and tail latency
-    serve_failures: list[str] = []
-    if args.serve_baseline:
-        serve_failures = serve_gate(args)
-
-    if failures or speedup_failures or replay_failures or serve_failures or socket_failures:
+    if failures or speedup_failures or replay_failures or socket_failures:
         if replay_failures:
             print(f"\nFAIL: §12 replay gate: {', '.join(replay_failures)}")
         if socket_failures:
             print(f"\nFAIL: §16 socket gate: {', '.join(socket_failures)}")
-        if serve_failures:
-            print(f"\nFAIL: §13 serve gate: {', '.join(serve_failures)}")
         if failures:
             print(
                 f"\nFAIL: overhead regression >{args.threshold}x in: "

@@ -7,16 +7,13 @@ Sections:
   shapes      chain/wide/wavefront task graphs (Taskflow suite shapes)
   overlap     GIL-releasing overlap (the TPU-host regime)
   pipeline    task-graph-derived 1F1B vs GPipe schedule quality
-  roofline    summarises dry-run artifacts if present (benchmarks/artifacts/)
 
 Env:
   BENCH_FAST=1   smaller fib sizes / fewer repeats (CI mode)
 """
 from __future__ import annotations
 
-import json
 import os
-import pathlib
 
 
 def _emit(name: str, us_per_call: float, derived: str) -> None:
@@ -73,34 +70,10 @@ def section_pipeline_schedules() -> None:
         )
 
 
-def section_roofline() -> None:
-    art = pathlib.Path(__file__).parent / "artifacts"
-    files = sorted(art.glob("*.json")) if art.exists() else []
-    if not files:
-        print("\n# roofline: no dry-run artifacts yet (run launch/dryrun.py)")
-        return
-    print("\n# roofline terms from dry-run artifacts (see EXPERIMENTS.md §Roofline)")
-    for f in files:
-        try:
-            d = json.loads(f.read_text())
-        except Exception:
-            continue
-        r = d.get("roofline", {})
-        if not r:
-            continue
-        _emit(
-            f"roofline/{d.get('arch')}/{d.get('shape')}/{d.get('mesh')}",
-            r.get("dominant_s", 0.0) * 1e6,
-            f"compute_s={r.get('compute_s', 0):.3e};memory_s={r.get('memory_s', 0):.3e};"
-            f"collective_s={r.get('collective_s', 0):.3e};dominant={r.get('dominant', '?')}",
-        )
-
-
 def main() -> None:
     fast = os.environ.get("BENCH_FAST", "0") == "1"
     section_paper(fast)
     section_pipeline_schedules()
-    section_roofline()
 
 
 if __name__ == "__main__":

@@ -30,19 +30,19 @@ import time
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent
-sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
+sys.path.insert(0, str(ROOT / "src"))
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from benchmarks.serve_bench import sequential_reference  # noqa: E402
 from repro.configs import get_config  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_host_mesh  # noqa: E402
 from repro.models import build_model  # noqa: E402
 from repro.runtime import Trainer, TrainerConfig  # noqa: E402
 from repro.serve import ServeEngine  # noqa: E402
+from repro.serve.kv import pad_caches_to  # noqa: E402
 
 ARCH = "tinyllama-1.1b"
 
@@ -75,6 +75,27 @@ def make_requests(vocab: int, seed: int):
     ]
     budgets = [int(rng.integers(NEW_TOKENS[0], NEW_TOKENS[1] + 1)) for _ in range(REQUESTS)]
     return prompts, budgets
+
+
+def sequential_reference(model, params, prompts, budgets, width: int):
+    """Each request decoded alone, greedily, one token at a time, with its
+    prefill cache zero-padded to ``width`` (the engine's max_len), so both
+    programs attend over caches of one width: in bf16 the reduction tiling
+    over differently padded widths can flip argmax at a near-tie."""
+    prefill = jax.jit(model.prefill)
+    decode = jax.jit(model.decode_step, donate_argnums=(2,))
+    outs = []
+    for prompt, budget in zip(prompts, budgets):
+        logits, caches = prefill(params, {"tokens": jnp.asarray(prompt[None, :])})
+        caches = pad_caches_to(caches, width - int(prompt.size))
+        tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
+        out = [int(tok[0, 0])]
+        for i in range(budget - 1):
+            logits, caches = decode(params, tok, caches, jnp.asarray(prompt.size + i, jnp.int32))
+            tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
+            out.append(int(tok[0, 0]))
+        outs.append(out)
+    return outs
 
 
 def near_tie(prefill32, params32, prompt, prefix, toks, width: int) -> dict:

@@ -563,29 +563,5 @@ def _head_major(stored: dict) -> dict:
     return {g: gqa_head_major(a) for g, a in stored.items()}
 
 
-def extend_caches(caches: dict, extra: int, *, window: Optional[int] = None) -> dict:
-    """Pad attention caches by ``extra`` positions (decode continuation).
-
-    Thin wrapper kept for API stability: the per-family cache-layout walk
-    now lives in ``repro.serve.kv`` (imported lazily — models must not
-    depend on the serving layer at import time), which also powers the
-    slot-based serving cache.
-
-    ``window``: when given, sliding-window ring buffers are re-laid out to
-    the full ``min(window, prompt + extra)`` modulus. Without it a ring
-    prefilled from a prompt shorter than the window keeps its undersized
-    modulus and evicts keys that are still inside the attention window —
-    the historical behavior, preserved for callers that don't pass cfg.
-    """
-    from repro.serve.kv import pad_caches_to, ring_modulus
-
-    ring_w = None
-    if window is not None:
-        w0 = ring_modulus(caches)
-        if w0 is not None:
-            ring_w = min(window, w0 + extra)
-    return pad_caches_to(caches, extra, ring_w=ring_w)
-
-
 def build_model(cfg, scan_probe: Optional[int] = None, scan_unroll: bool = False) -> Model:
     return Model(cfg, scan_probe=scan_probe, scan_unroll=scan_unroll)

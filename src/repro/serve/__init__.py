@@ -2,10 +2,9 @@
 thread pool (DESIGN.md §7, §13).
 
 ``kv.py`` owns the per-family KV-cache layout knowledge (GQA append, MLA
-compressed latents, SSM recurrent state, sliding-window rings) as two
-cache pools — the flat per-slot :class:`SlotKVCache` and the block-pooled
-:class:`PagedKVCache` (fixed-size pages + per-sequence page tables);
-``engine.py`` schedules prefill/decode as prioritized tasks on the
+compressed latents, SSM recurrent state, sliding-window rings) and the
+block-pooled :class:`PagedKVCache` (fixed-size pages + per-sequence page
+tables); ``engine.py`` schedules prefill/decode as prioritized tasks on the
 work-stealing pool, batches sequences at iteration level, streams tokens
 per tick, and under page pressure preempts the youngest resident back to
 its deadline-ordered admit queue.
@@ -21,7 +20,7 @@ from .engine import (
     RequestHandle,
     ServeEngine,
 )
-from .kv import PagedKVCache, SlotKVCache, pad_caches_to
+from .kv import PagedKVCache, pad_caches_to
 
 __all__ = [
     "ServeEngine",
@@ -29,7 +28,6 @@ __all__ = [
     "RequestHandle",
     "QueueFull",
     "DeadlineExceeded",
-    "SlotKVCache",
     "PagedKVCache",
     "pad_caches_to",
     "PREFILL_PRIORITY",

@@ -29,18 +29,16 @@ running to the longest member. One tick is one jitted
 ``vmap(model.decode_step)`` over the slot axis with a per-slot write index —
 sequences of different lengths share one decode computation.
 
-KV storage defaults to the **paged** layout (:class:`~repro.serve.kv.
-PagedKVCache`): each tick's attention reads the resident sequences' K/V
-pages in place through the page table (a Pallas kernel on TPU), and each
-lane's new token is written into its page; MLA latents are still gathered
-into the logical slot batch and their touched page scattered back.
-Admission holds pages for the prefilled prompt only; decode growth claims
-pages one at a time, and on page pressure the engine
-**preempts the youngest resident** — its pages are freed and the request
-re-enters the admit queue (at its original deadline/arrival key) to resume
-later by re-prefilling its prompt + generated prefix. Preemption moves
-work, it never drops it. ``kv_layout="flat"`` keeps the original
-whole-slot :class:`~repro.serve.kv.SlotKVCache` for comparison.
+KV storage is paged (:class:`~repro.serve.kv.PagedKVCache`): each tick's
+attention reads the resident sequences' K/V pages in place through the
+page table (a Pallas kernel on TPU), and each lane's new token is written
+into its page; MLA latents are still gathered into the logical slot batch
+and their touched page scattered back. Admission holds pages for the
+prefilled prompt only; decode growth claims pages one at a time, and on
+page pressure the engine **preempts the youngest resident** — its pages
+are freed and the request re-enters the admit queue (at its original
+deadline/arrival key) to resume later by re-prefilling its prompt +
+generated prefix. Preemption moves work, it never drops it.
 
 Tokens are **streamed**: every decode tick pushes each lane's new token to
 its :class:`RequestHandle`, which exposes a blocking iterator
@@ -112,7 +110,7 @@ from repro.core import (
 )
 from repro.models.attention import HEAD_MAJOR
 
-from .kv import PagedKVCache, SlotKVCache
+from .kv import PagedKVCache
 
 __all__ = [
     "ServeEngine",
@@ -420,13 +418,15 @@ class ServeEngine:
         Per-sequence cache capacity (prompt + generated). Sequences reaching
         it are evicted (``handle.truncated``).
     kv_layout:
-        ``"paged"`` (default) stores growable cache leaves in fixed-size
-        pages with per-sequence page tables (DESIGN.md §13) — admission
-        holds pages for the prompt only, growth is O(1) page claims, and
-        page pressure preempts the youngest resident to the admit queue
-        instead of refusing work. ``"flat"`` keeps the whole-slot layout.
+        Only ``"paged"``, the one layout: growable cache leaves live in
+        fixed-size pages with per-sequence page tables (DESIGN.md §13) —
+        admission holds pages for the prompt only, growth is O(1) page
+        claims, and page pressure preempts the youngest resident to the
+        admit queue instead of refusing work. The keyword remains because
+        the chat benchmark's traffic file still passes it; any other value
+        raises ``ValueError``.
     page_size, num_pages:
-        Paged layout knobs: tokens per page, and the usable page-pool size.
+        Tokens per page, and the usable page-pool size.
         ``num_pages`` defaults to ``max_slots * ceil(max_len / page_size)``
         (every resident can reach ``max_len`` — no preemption); smaller
         values oversubscribe memory and rely on preemption.
@@ -487,6 +487,8 @@ class ServeEngine:
         breaker_threshold: int = 5,
         breaker_cooldown: float = 1.0,
     ) -> None:
+        if kv_layout != "paged":
+            raise ValueError(f"kv_layout must be 'paged', the one layout; got {kv_layout!r}")
         cfg = model.cfg
         if cfg.is_encdec or cfg.family == "vlm":
             raise NotImplementedError(
@@ -531,34 +533,21 @@ class ServeEngine:
         self._breaker_until = 0.0  # monotonic time the breaker re-closes
         self._breaker_trips = 0
         self._prefill_jit = jax.jit(model.prefill)
+        self.kv = kv = PagedKVCache(
+            model, max_slots, max_len, page_size=page_size, num_pages=num_pages
+        )
 
-        def _step(p, tok, cache, idx):
-            logits, cache = model.decode_step(p, tok, cache, idx)
-            return jnp.argmax(logits[:, -1], -1).astype(jnp.int32), cache
+        def _ptick(p, tok, pools, tables, dest, idx):
+            def lane(tok, part, table, i):
+                cache = kv.lane_view(pools, part, table, i)
+                logits, cache = model.decode_step(p, tok, cache, i)
+                return jnp.argmax(logits[:, -1], -1).astype(jnp.int32), cache
 
-        if kv_layout == "paged":
-            self.kv = PagedKVCache(
-                model, max_slots, max_len, page_size=page_size, num_pages=num_pages
-            )
-            kv = self.kv
+            parts = kv.lane_caches(pools, tables)
+            toks, upd = jax.vmap(lane)(tok, parts, tables, idx)
+            return toks, kv.commit(pools, upd, dest, idx)
 
-            def _ptick(p, tok, pools, tables, dest, idx):
-                def lane(tok, part, table, i):
-                    return _step(p, tok, kv.lane_view(pools, part, table, i), i)
-
-                parts = kv.lane_caches(pools, tables)
-                toks, upd = jax.vmap(lane)(tok, parts, tables, idx)
-                return toks, kv.commit(pools, upd, dest, idx)
-
-            self._tick_jit = jax.jit(_ptick, donate_argnums=(2,))
-        elif kv_layout == "flat":
-            self.kv = SlotKVCache(model, max_slots, max_len)
-            self._tick_jit = jax.jit(
-                jax.vmap(_step, in_axes=(None, 0, 0, 0)), donate_argnums=(2,)
-            )
-        else:
-            raise ValueError(f"kv_layout must be 'paged' or 'flat', got {kv_layout!r}")
-        self._paged = kv_layout == "paged"
+        self._tick_jit = jax.jit(_ptick, donate_argnums=(2,))
 
         self._lock = threading.Lock()
         self._idle = threading.Condition(self._lock)
@@ -1028,8 +1017,8 @@ class ServeEngine:
 
     def _tick_body(self) -> None:
         tracer = self.tracer  # one sink for the whole tick, even across close()
-        # 1. join freshly prefilled sequences into free slots (paged: the
-        #    join claims pages for the prefilled prompt only)
+        # 1. join freshly prefilled sequences into free slots (the join
+        #    claims pages for the prefilled prompt only)
         with _span(tracer, "serve.tick.join") as late:
             with self._lock:
                 joins = []
@@ -1090,25 +1079,19 @@ class ServeEngine:
                     feeds[slot] = seq.feed_index
                 self._ticks += 1
                 self._occupancy_sum += len(self._active)
-            if self._paged:
-                tables, dest = self.kv.tick_inputs(feeds)
+            tables, dest = self.kv.tick_inputs(feeds)
 
         # 2. one decode step over the padded slot batch (outside the lock):
         #    the call returns once enqueued; the sync is the host's wait
         with _span(tracer, "serve.tick.dispatch"):
-            if self._paged:
-                next_toks, self.kv.pools = self._tick_jit(
-                    self.params,
-                    jnp.asarray(tok_np),
-                    self.kv.pools,
-                    jnp.asarray(tables),
-                    jnp.asarray(dest),
-                    jnp.asarray(idx_np),
-                )
-            else:
-                next_toks, self.kv.buffers = self._tick_jit(
-                    self.params, jnp.asarray(tok_np), self.kv.buffers, jnp.asarray(idx_np)
-                )
+            next_toks, self.kv.pools = self._tick_jit(
+                self.params,
+                jnp.asarray(tok_np),
+                self.kv.pools,
+                jnp.asarray(tables),
+                jnp.asarray(dest),
+                jnp.asarray(idx_np),
+            )
         with _span(tracer, "serve.tick.sync"):
             next_np = np.asarray(next_toks)  # (slots, 1)
 

@@ -1,11 +1,9 @@
-"""Slot-based KV-cache pool for continuous batching (DESIGN.md §7).
+"""Paged KV-cache pool for continuous batching (DESIGN.md §7, §13).
 
-The per-family cache-layout knowledge that used to live inside
-``models/lm.py`` (``extend_caches``) is concentrated here: how each cache
-kind grows along its sequence axis, and how the kinds that *don't* grow
+The per-family cache-layout knowledge lives here: how each cache kind
+grows along its sequence axis, and how the kinds that *don't* grow
 (sliding-window rings, SSM recurrent state, static cross-attention K/V)
-pass through. ``models.lm.extend_caches`` now delegates to
-:func:`pad_caches_to`.
+pass through.
 
 Cache kinds, by leaf signature:
 
@@ -17,24 +15,20 @@ Cache kinds, by leaf signature:
 * anything else             SSM state / conv stream / static encoder K/V —
                             fixed size, pass through.
 
-:class:`SlotKVCache` pools these per-sequence caches: one big buffer tree
-whose leading axis is the *slot* index, each slot holding a batch-1 cache of
-length ``max_len``. Sequences of different lengths then share one padded
-decode batch — the engine vmaps the model's single-token ``decode_step``
-over the slot axis with a per-slot write index.
-
-:class:`PagedKVCache` (DESIGN.md §13) replaces the flat per-slot layout
-with a pool of fixed-size *pages*: every growable leaf (GQA append K/V, MLA
-latents) is stored in pages, with a free-list of physical page ids and a
-per-slot page table; fixed-size leaves (SSM state, sliding-window rings,
-static encoder K/V) stay slot-indexed exactly as in the flat cache. Prefill
-installs only the pages a prompt actually covers (O(pages touched), not
-O(max_len)), and growth is appending one page id to a table row. The decode
-tick reads GQA K/V in place, through the page table
-(:class:`~repro.models.attention.PagedKV`), and writes each lane's new token
-into its page; MLA latents are still reassembled into each slot's logical
-cache by a gather — bit-identical to the flat layout because unmapped
-table entries point at a reserved always-zero page.
+:class:`PagedKVCache` pools these per-sequence caches as fixed-size
+*pages*: every growable leaf (GQA append K/V, MLA latents) is stored in
+pages, with a free-list of physical page ids and a per-slot page table;
+fixed-size leaves (SSM state, sliding-window rings, static encoder K/V)
+stay slot-indexed, one whole batch-1 leaf per slot. Prefill installs only
+the pages a prompt actually covers (O(pages touched), not O(max_len)), and
+growth is appending one page id to a table row. The engine vmaps the
+model's single-token ``decode_step`` over the slots with a per-slot write
+index; the tick reads GQA K/V in place, through the page table
+(:class:`~repro.models.attention.PagedKV`), and writes each lane's new
+token into its page; MLA latents are still reassembled into each slot's
+logical cache by a gather — equal to the prefill cache zero-padded by
+:func:`pad_caches_to`, because unmapped table entries point at a reserved
+always-zero page.
 """
 from __future__ import annotations
 
@@ -134,182 +128,16 @@ def pad_caches_to(caches: dict, extra: int, *, ring_w: Optional[int] = None) -> 
     return walk(caches)
 
 
-def _ring_modulus(node: Any, acc: list) -> None:
-    if _is_gqa(node) and "pos" in node:
-        acc.append(node["pos"].shape[-1])
-    elif isinstance(node, dict):
-        for v in node.values():
-            _ring_modulus(v, acc)
-
-
-def ring_modulus(caches: dict) -> Optional[int]:
+def ring_modulus(caches: Any) -> Optional[int]:
     """Modulus of the sliding-window ring leaves, or None if there are none."""
-    acc: list = []
-    _ring_modulus(caches, acc)
-    return acc[0] if acc else None
-
-
-# ---------------------------------------------------------------------------
-# slot pool
-# ---------------------------------------------------------------------------
-
-
-class SlotKVCache:
-    """A pool of ``max_slots`` per-sequence caches sharing one buffer tree.
-
-    Every leaf of ``buffers`` has shape ``(max_slots, *leaf_b1)`` where
-    ``leaf_b1`` is the model's batch-1 cache shape at length ``max_len``
-    (from ``model.cache_shapes(1, max_len)``). Allocation is a free-list;
-    ``write`` pads a freshly prefilled batch-1 cache out to ``max_len`` and
-    overwrites one slot in a single donated jit (no host round-trip).
-
-    Thread safety: alloc/free/evict are lock-protected; ``write`` and the
-    engine's decode tick mutate ``buffers`` and must be serialized by the
-    caller (the engine's tick chain does this).
-    """
-
-    def __init__(self, model, max_slots: int, max_len: int) -> None:
-        if max_slots < 1 or max_len < 1:
-            raise ValueError("max_slots and max_len must be >= 1")
-        self.max_slots = max_slots
-        self.max_len = max_len
-        self._slot_shapes = model.cache_shapes(1, max_len)
-        self.buffers = jax.tree.map(
-            lambda s: jnp.zeros((max_slots, *s.shape), s.dtype), self._slot_shapes
-        )
-        rings: list = []
-        _ring_modulus(self._slot_shapes, rings)
-        self._ring_w = rings[0] if rings else None
-        self._lock = threading.Lock()
-        self._free = list(range(max_slots - 1, -1, -1))  # pop() -> lowest slot
-        self._live: set[int] = set()
-        self.allocs = 0
-        self.frees = 0
-        self.evictions = 0
-        self.peak_live = 0
-        # tokens each live slot is provisioned to hold (written prefill +
-        # decode growth intent) — powers the fragmentation stat: a flat
-        # slot always reserves max_len, whatever the sequence needs
-        self._target_len = [0] * max_slots
-
-        def _write(buffers, new_cache, slot, prefill_len):
-            padded = pad_caches_to(
-                new_cache, self.max_len - prefill_len, ring_w=self._ring_w
-            )
-            return jax.tree.map(lambda b, n: b.at[slot].set(n), buffers, padded)
-
-        # one jit; retraces per distinct prefill length (bucketed upstream)
-        self._write_jit = jax.jit(_write, donate_argnums=(0,), static_argnums=(3,))
-
-    # -- slot lifecycle -------------------------------------------------------
-
-    @property
-    def num_free(self) -> int:
-        return len(self._free)
-
-    @property
-    def num_live(self) -> int:
-        return len(self._live)
-
-    def pages_for(self, length: int) -> int:
-        """Pages a sequence of ``length`` tokens needs. A flat slot is one
-        indivisible max_len-sized page, so the answer is always 1."""
-        return 1
-
-    def capacity_tokens(self, slot: int) -> int:
-        """Token positions currently backed by storage for ``slot``."""
-        return self.max_len
-
-    def alloc(self, npages: int = 1) -> Optional[int]:
-        """Claim a slot, or None when the pool is exhausted.
-
-        ``npages`` is accepted for interface parity with
-        :class:`PagedKVCache`; a flat slot always provisions max_len.
-        """
-        with self._lock:
-            if not self._free:
-                return None
-            slot = self._free.pop()
-            self._live.add(slot)
-            self.allocs += 1
-            self.peak_live = max(self.peak_live, len(self._live))
-            return slot
-
-    def grow_to(self, slot: int, length: int) -> bool:
-        """Extend ``slot``'s provisioned length. Flat slots pre-provision
-        max_len, so growth within capacity always succeeds."""
-        if length > self.max_len:
-            return False
-        with self._lock:
-            self._target_len[slot] = max(self._target_len[slot], length)
-        return True
-
-    def free(self, slot: int) -> None:
-        """Return a slot to the pool (retired sequence)."""
-        with self._lock:
-            if slot not in self._live:
-                raise ValueError(f"slot {slot} is not live")
-            self._live.remove(slot)
-            self._free.append(slot)
-            self._target_len[slot] = 0
-            self.frees += 1
-
-    def evict(self, slot: int) -> None:
-        """Forcibly free a live slot (capacity eviction); counted separately."""
-        self.free(slot)
-        with self._lock:
-            self.evictions += 1
-
-    # -- data movement --------------------------------------------------------
-
-    def write(self, slot: int, cache: dict, prefill_len: int) -> None:
-        """Install a batch-1 prefill cache (length ``prefill_len``) into ``slot``.
-
-        Caller must hold the engine's tick serialization (buffers are
-        donated). The cache is padded/re-laid out to ``max_len`` on device.
-        """
-        if slot not in self._live:
-            raise ValueError(f"slot {slot} is not live")
-        if prefill_len > self.max_len:
-            raise ValueError(f"prefill length {prefill_len} exceeds max_len {self.max_len}")
-        with self._lock:
-            self._target_len[slot] = max(self._target_len[slot], prefill_len)
-        self.buffers = self._write_jit(
-            self.buffers, cache, jnp.asarray(slot, jnp.int32), prefill_len
-        )
-
-    def read_slot(self, slot: int) -> dict:
-        """The batch-1 cache tree currently stored in ``slot`` (for tests)."""
-        return jax.tree.map(lambda b: b[slot], self.buffers)
-
-    def stats(self) -> dict:
-        """Lifecycle counters plus the §13 occupancy/fragmentation pair.
-
-        For the flat layout one slot == one max_len-sized page:
-        ``page_occupancy`` is slot occupancy and ``fragmentation`` is the
-        fraction of provisioned token capacity the live sequences don't
-        actually need — the over-allocation the paged cache exists to
-        eliminate.
-        """
-        with self._lock:
-            live = len(self._live)
-            used = sum(self._target_len[s] for s in self._live)
-            cap = live * self.max_len
-            return {
-                "max_slots": self.max_slots,
-                "live": live,
-                "free": len(self._free),
-                "allocs": self.allocs,
-                "frees": self.frees,
-                "evictions": self.evictions,
-                "peak_live": self.peak_live,
-                "page_size": self.max_len,
-                "pages_total": self.max_slots,
-                "pages_live": live,
-                "pages_free": len(self._free),
-                "page_occupancy": live / self.max_slots,
-                "fragmentation": (1.0 - used / cap) if cap else 0.0,
-            }
+    if _is_gqa(caches) and "pos" in caches:
+        return caches["pos"].shape[-1]
+    if isinstance(caches, dict):
+        for node in caches.values():
+            w = ring_modulus(node)
+            if w is not None:
+                return w
+    return None
 
 
 # ---------------------------------------------------------------------------
@@ -387,13 +215,14 @@ class PagedKVCache:
       axis of the batch-1 slot layout is replaced by ``page_size`` and the
       physical page id leads;
     * *fixed-size* leaves (SSM recurrent state, conv streams, sliding-window
-      rings, static encoder K/V) keep the flat ``(max_slots, ...)`` layout —
+      rings, static encoder K/V) are held whole, ``(max_slots, ...)`` —
       they never grow, so paging them buys nothing;
     * two physical pages are reserved: page 0 is the **zero page** (never
       written; every unmapped page-table entry points at it, so a gathered
-      logical cache is zero-padded exactly like the flat layout — the
-      bit-identity invariant), page 1 is the **scratch page** (decode
-      writes from inactive batch lanes land there and are never read).
+      logical cache equals the prefill cache zero-padded by
+      :func:`pad_caches_to` — the bit-identity invariant), page 1 is the
+      **scratch page** (decode writes from inactive batch lanes land there
+      and are never read).
 
     Allocation is a free-list of physical page ids; the per-slot page table
     is a host-side ``(max_slots, pages_per_seq)`` int32 array shipped to the
@@ -409,9 +238,9 @@ class PagedKVCache:
     writes each lane's new GQA token into its page and the single MLA page
     containing its write index (inactive lanes → scratch page).
 
-    Thread safety matches :class:`SlotKVCache`: page/slot accounting is
-    lock-protected; ``write`` and the decode tick mutate ``pools`` and must
-    be serialized by the caller (the engine's tick chain does this).
+    Thread safety: page/slot accounting is lock-protected; ``write`` and
+    the decode tick mutate ``pools`` and must be serialized by the caller
+    (the engine's tick chain does this).
     """
 
     ZERO_PAGE = 0
@@ -447,9 +276,7 @@ class PagedKVCache:
         # how the decode tick reads each paged leaf: in place or gathered
         kinds = [s.kind for s in jax.tree.leaves(self._spec_tree, is_leaf=_is_spec)]
         self._leaves = {k: kinds.count(k) for k in ("kv", "page")}
-        rings: list = []
-        _ring_modulus(self._slot_shapes, rings)
-        self._ring_w = rings[0] if rings else None
+        self._ring_w = ring_modulus(self._slot_shapes)
 
         ps, nphys = self.page_size, self.RESERVED + num_pages
 
@@ -622,7 +449,8 @@ class PagedKVCache:
 
         Pure/traceable; ``tables`` is the device copy of the page table.
         Unmapped entries point at the zero page, so the result is
-        bit-identical to the flat slot layout. With ``in_place=False`` the
+        bit-identical to the prefill cache zero-padded by
+        :func:`pad_caches_to`. With ``in_place=False`` the
         leaves the decode tick reads in place are left out (None).
         """
         ps = self.page_size
@@ -677,8 +505,7 @@ class PagedKVCache:
         layer), written at its offset in physical page ``dest_ids[slot]``;
         an MLA leaf holds its whole logical cache, of which the single page
         containing the position goes to ``dest_ids[slot]``. Inactive lanes
-        write to the scratch page. Fixed-size leaves are replaced wholesale,
-        exactly like the flat layout.
+        write to the scratch page. Fixed-size leaves are replaced wholesale.
         """
         ps = self.page_size
         off = idx % ps
@@ -735,8 +562,7 @@ class PagedKVCache:
         ``page_occupancy``: fraction of the usable page pool currently
         mapped by live sequences. ``fragmentation``: fraction of the token
         capacity inside those live pages that no sequence needs (internal
-        fragmentation — bounded by ``page_size - 1`` tokens per sequence,
-        versus up to ``max_len - prompt`` per sequence for the flat layout).
+        fragmentation — bounded by ``page_size - 1`` tokens per sequence).
         ``leaves_in_place`` / ``leaves_gathered``: how many paged cache
         leaves (a scan-stacked leaf counts once) the decode tick reads in
         place through the page table, and how many it still gathers into a

@@ -61,9 +61,9 @@ def test_reduced_prefill_decode_shapes(arch):
     logits, caches = jax.jit(model.prefill)(params, batch)
     assert logits.shape == (B, 1, cfg.vocab_size)
     assert jnp.all(jnp.isfinite(logits.astype(jnp.float32)))
-    from repro.models.lm import extend_caches
+    from repro.serve.kv import pad_caches_to
 
-    caches = extend_caches(caches, 2)
+    caches = pad_caches_to(caches, 2)
     tok = jnp.zeros((B, 1), jnp.int32)
     logits2, caches2 = jax.jit(model.decode_step)(
         params, tok, caches, jnp.array(S, jnp.int32)

@@ -188,7 +188,7 @@ def test_decode_step_sharded_matches_single_device():
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_reduced
     from repro.models import build_model
-    from repro.models.lm import extend_caches
+    from repro.serve.kv import pad_caches_to
     from repro.launch.mesh import make_host_mesh
     from repro.parallel.steps import build_decode_step
 
@@ -198,7 +198,7 @@ def test_decode_step_sharded_matches_single_device():
     B, S = 4, 8
     tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab_size)
     logits0, caches = jax.jit(model.prefill)(params, {"tokens": tokens})
-    caches = extend_caches(caches, 4)
+    caches = pad_caches_to(caches, 4)
     tok = jnp.zeros((B, 1), jnp.int32)
     ref_logits, _ = jax.jit(model.decode_step)(params, tok, caches, jnp.asarray(S))
 

@@ -6,14 +6,14 @@ import time
 
 import numpy as np
 import jax
-import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_reduced
 from repro.core import CancelledError, ThreadPool
 from repro.models import build_model
-from repro.models.lm import extend_caches
 from repro.serve import RequestHandle, ServeEngine
+
+from decode_reference import sequential_decode
 
 
 def _build(arch):
@@ -21,21 +21,6 @@ def _build(arch):
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     return cfg, model, params
-
-
-def sequential_decode(model, params, prompt, budget, width):
-    """The pre-existing single-request path, provisioned at ``width`` KV
-    capacity (the engine's max_len) so both programs mask identically."""
-    logits, caches = jax.jit(model.prefill)(params, {"tokens": jnp.asarray(prompt[None, :])})
-    caches = extend_caches(caches, width - int(prompt.size))
-    tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
-    out = [int(tok[0, 0])]
-    decode = jax.jit(model.decode_step)
-    for i in range(budget - 1):
-        logits, caches = decode(params, tok, caches, jnp.asarray(prompt.size + i, jnp.int32))
-        tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
-        out.append(int(tok[0, 0]))
-    return out
 
 
 def test_continuous_batching_matches_single_request_decode():
@@ -136,6 +121,9 @@ def test_rejects_unsupported_configs():
     cfg_e, model_e, _ = _build("whisper-medium")
     with pytest.raises(NotImplementedError):
         ServeEngine(model_e, None)
+    for layout in ("flat", "contiguous"):  # paged is the one KV layout
+        with pytest.raises(ValueError, match="'paged'"):
+            ServeEngine(model, None, kv_layout=layout)
 
 
 def test_validates_requests_and_shares_pool():

@@ -1,7 +1,7 @@
 """The paged decode tick: attention reads the KV pages in place.
 
-Against the flat layout, token for token, for a dense, an MoE, an MLA and a
-hybrid configuration; one tick program for every occupancy and length; the
+Against sequential decode, token for token, for a dense, an MoE, an MLA and
+a hybrid configuration; one tick program for every occupancy and length; the
 KV counter in ``stats()``; and the Pallas kernel itself (interpret mode) in
 the tick in place of its oracle. The engine serves the Q/K/V projections
 head-major: bit for bit what the stored tree computes, counted in
@@ -17,8 +17,10 @@ import pytest
 from repro.configs import get_reduced
 from repro.kernels import ops, ref
 from repro.models import build_model
-from repro.models.lm import extend_caches
 from repro.serve import ServeEngine
+from repro.serve.kv import pad_caches_to
+
+from decode_reference import sequential_decode
 
 MAX_LEN, PAGE = 24, 4
 
@@ -38,10 +40,8 @@ def _requests(cfg, n=5, seed=0):
     return prompts, [int(b) for b in rng.integers(2, 10, size=n)]
 
 
-def _serve(model, params, prompts, budgets, layout):
-    with ServeEngine(
-        model, params, max_slots=3, max_len=MAX_LEN, kv_layout=layout, page_size=PAGE
-    ) as engine:
+def _serve(model, params, prompts, budgets):
+    with ServeEngine(model, params, max_slots=3, max_len=MAX_LEN, page_size=PAGE) as engine:
         outs = engine.generate(prompts, budgets, timeout=300)
         return [list(map(int, o)) for o in outs], engine.stats()
 
@@ -55,12 +55,12 @@ def _serve(model, params, prompts, budgets, layout):
         ("hymba-1.5b", 6, 0),  # three global layers; rings and SSM state are slot leaves
     ],
 )
-def test_paged_tick_streams_the_flat_layouts_tokens(arch, in_place, gathered):
+def test_paged_tick_streams_sequential_decodes_tokens(arch, in_place, gathered):
     cfg, model, params = _model(arch)
     prompts, budgets = _requests(cfg)
-    flat, _ = _serve(model, params, prompts, budgets, "flat")
-    paged, stats = _serve(model, params, prompts, budgets, "paged")
-    assert paged == flat
+    refs = [sequential_decode(model, params, p, b, MAX_LEN) for p, b in zip(prompts, budgets)]
+    paged, stats = _serve(model, params, prompts, budgets)
+    assert paged == refs
     assert (stats["kv"]["leaves_in_place"], stats["kv"]["leaves_gathered"]) == (in_place, gathered)
 
 
@@ -91,11 +91,11 @@ def test_the_pallas_kernel_in_the_tick_streams_the_oracles_tokens(monkeypatch):
     the lanes of the vmapped decode step join one kernel call per layer."""
     cfg, model, params = _model("phi4-mini-3.8b")  # padded heads: 48 / 16
     prompts, budgets = _requests(cfg, n=4, seed=1)
-    oracle, _ = _serve(model, params, prompts, budgets, "paged")
+    oracle, _ = _serve(model, params, prompts, budgets)
     monkeypatch.setattr(
         ref, "paged_attention_ref", functools.partial(ops.paged_attention, interpret=True)
     )
-    kernel, _ = _serve(model, params, prompts, budgets, "paged")
+    kernel, _ = _serve(model, params, prompts, budgets)
     assert kernel == oracle
 
 
@@ -116,7 +116,7 @@ def test_the_serving_form_computes_the_stored_forms_logits_bit_for_bit():
     jax.tree.map(np.testing.assert_array_equal, caches["stored"], caches["serving"])
     np.testing.assert_array_equal(logits["stored"], logits["serving"])
     tok = jnp.argmax(logits["stored"][:, -1], -1)[:, None].astype(jnp.int32)
-    cache, idx = extend_caches(caches["stored"], 4), jnp.asarray(9, jnp.int32)
+    cache, idx = pad_caches_to(caches["stored"], 4), jnp.asarray(9, jnp.int32)
     (ls, cs), (lh, ch) = step(params, tok, cache, idx), step(served, tok, cache, idx)
     np.testing.assert_array_equal(ls, lh)
     jax.tree.map(np.testing.assert_array_equal, cs, ch)
@@ -125,9 +125,9 @@ def test_the_serving_form_computes_the_stored_forms_logits_bit_for_bit():
 def test_the_tick_streams_the_stored_forms_tokens_and_leaves_the_callers_tree(monkeypatch):
     cfg, model, params = _model("phi4-mini-3.8b")
     prompts, budgets = _requests(cfg, n=5, seed=2)
-    served, stats = _serve(model, params, prompts, budgets, "paged")
+    served, stats = _serve(model, params, prompts, budgets)
     monkeypatch.setattr(model, "serving_params", lambda p: p)
-    stored, stats_stored = _serve(model, params, prompts, budgets, "paged")
+    stored, stats_stored = _serve(model, params, prompts, budgets)
     assert served == stored
     assert (stats["params"], stats_stored["params"]) == (3, 0)
     # the caller's arrays were not donated: still whole and usable

@@ -1,5 +1,6 @@
-"""Slot cache tests: alloc/free/evict lifecycle, per-family pad walks,
-ring re-layout, and write/read roundtrips through a real model prefill."""
+"""Paged cache tests: alloc/free/evict lifecycle, per-family pad walks,
+ring re-layout, page accounting, and write/read roundtrips through a real
+model prefill."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,7 +8,7 @@ import pytest
 
 from repro.configs import get_reduced
 from repro.models import build_model
-from repro.serve.kv import PagedKVCache, SlotKVCache, pad_caches_to, ring_modulus
+from repro.serve.kv import PagedKVCache, pad_caches_to, ring_modulus
 
 
 def _tiny_model(arch="tinyllama-1.1b"):
@@ -24,7 +25,7 @@ def _tiny_model(arch="tinyllama-1.1b"):
 
 def test_alloc_free_exhaustion():
     _cfg, model, _params = _tiny_model()
-    kv = SlotKVCache(model, max_slots=3, max_len=8)
+    kv = PagedKVCache(model, max_slots=3, max_len=8, page_size=4)
     slots = [kv.alloc() for _ in range(3)]
     assert sorted(slots) == [0, 1, 2]
     assert kv.alloc() is None  # exhausted
@@ -37,7 +38,7 @@ def test_alloc_free_exhaustion():
 
 def test_eviction_is_counted_and_reusable():
     _cfg, model, _params = _tiny_model()
-    kv = SlotKVCache(model, max_slots=2, max_len=8)
+    kv = PagedKVCache(model, max_slots=2, max_len=8, page_size=4)
     a = kv.alloc()
     kv.evict(a)
     assert kv.stats()["evictions"] == 1
@@ -98,8 +99,8 @@ def test_write_roundtrip_matches_prefill():
     tokens = jax.random.randint(jax.random.PRNGKey(1), (1, S), 0, cfg.vocab_size)
     _logits, cache = jax.jit(model.prefill)(params, {"tokens": tokens})
 
-    kv = SlotKVCache(model, max_slots=2, max_len=MAX)
-    slot = kv.alloc()
+    kv = PagedKVCache(model, max_slots=2, max_len=MAX, page_size=4)
+    slot = kv.alloc(kv.pages_for(S))
     kv.write(slot, cache, S)
     got = kv.read_slot(slot)
 
@@ -120,10 +121,12 @@ def test_write_roundtrip_matches_prefill():
 
 
 def test_write_rejects_dead_slot_and_overflow():
+    """One page the size of the whole sequence: the slot holds every
+    position a write may name, so only a dead slot and overflow refuse."""
     cfg, model, params = _tiny_model()
     tokens = jnp.zeros((1, 4), jnp.int32)
     _logits, cache = jax.jit(model.prefill)(params, {"tokens": tokens})
-    kv = SlotKVCache(model, max_slots=1, max_len=8)
+    kv = PagedKVCache(model, max_slots=1, max_len=8, page_size=8)
     with pytest.raises(ValueError):
         kv.write(0, cache, 4)  # not allocated
     slot = kv.alloc()
@@ -181,24 +184,14 @@ def test_paged_validates_sizing():
 
 
 def test_paged_occupancy_and_fragmentation_stats():
-    """§13 satellite: both cache layouts report page-occupancy and internal
-    fragmentation; the paged layout's fragmentation is bounded by the page
-    size while the flat layout reserves max_len whatever the need."""
+    """§13 satellite: the cache reports page-occupancy and internal
+    fragmentation, which a page bounds to ``page_size - 1`` tokens."""
     _cfg, model, _params = _tiny_model()
     MAX = 32
-    flat = SlotKVCache(model, max_slots=2, max_len=MAX)
     paged = PagedKVCache(model, max_slots=2, max_len=MAX, page_size=8)
-    for kv in (flat, paged):
-        st = kv.stats()
-        assert st["pages_live"] == 0 and st["page_occupancy"] == 0.0
-        assert st["fragmentation"] == 0.0  # vacuously: nothing live
-
-    fs = flat.alloc()
-    flat.grow_to(fs, 10)  # a 10-token sequence in a 32-token slot
-    st = flat.stats()
-    assert st["page_size"] == MAX and st["pages_total"] == 2
-    assert st["page_occupancy"] == 0.5
-    assert st["fragmentation"] == pytest.approx(1 - 10 / 32)  # 22 wasted
+    st = paged.stats()
+    assert st["pages_live"] == 0 and st["page_occupancy"] == 0.0
+    assert st["fragmentation"] == 0.0  # vacuously: nothing live
 
     ps = paged.alloc(paged.pages_for(10))  # 2 pages of 8
     paged.grow_to(ps, 10)
@@ -210,21 +203,21 @@ def test_paged_occupancy_and_fragmentation_stats():
 
 
 @pytest.mark.parametrize("arch", ["tinyllama-1.1b", "mamba2-1.3b"])
-def test_paged_write_read_matches_flat(arch):
+def test_paged_write_read_matches_the_padded_prefill(arch):
     """Bit-identity invariant: a prefill written to pages and gathered back
-    equals the flat slot layout exactly (zero page == zero padding)."""
+    equals the prefill cache zero-padded to max_len (zero page == zero
+    padding), rings grown to the cache's modulus."""
     cfg, model, params = _tiny_model(arch)
     S, MAX = 5, 16
     tokens = jax.random.randint(jax.random.PRNGKey(2), (1, S), 0, cfg.vocab_size)
     _logits, cache = jax.jit(model.prefill)(params, {"tokens": tokens})
 
-    flat = SlotKVCache(model, max_slots=2, max_len=MAX)
     paged = PagedKVCache(model, max_slots=2, max_len=MAX, page_size=4)
-    fs, ps = flat.alloc(), paged.alloc(paged.pages_for(S))
-    flat.write(fs, cache, S)
+    ps = paged.alloc(paged.pages_for(S))
     paged.write(ps, cache, S)
-    a, b = flat.read_slot(fs), paged.read_slot(ps)
-    eq = jax.tree.map(lambda x, y: bool((np.asarray(x) == np.asarray(y)).all()), a, b)
+    want = pad_caches_to(cache, MAX - S, ring_w=ring_modulus(model.cache_shapes(1, MAX)))
+    got = paged.read_slot(ps)
+    eq = jax.tree.map(lambda x, y: bool((np.asarray(x) == np.asarray(y)).all()), want, got)
     assert all(jax.tree.leaves(eq)), eq
 
 
