@@ -40,8 +40,10 @@ are freed and the request re-enters the admit queue (at its original
 deadline/arrival key) to resume later by re-prefilling its prompt +
 generated prefix. Preemption moves work, it never drops it.
 
-Tokens are **streamed**: every decode tick pushes each lane's new token to
-its :class:`RequestHandle`, which exposes a blocking iterator
+Tokens are **streamed** to each request's :class:`RequestHandle`: a fresh
+request's first token is pushed the moment its prefill completes (the join
+that follows only places the sequence in a slot), and every decode tick
+pushes each lane's new token. The handle exposes a blocking iterator
 (``for tok in handle``) and an ``async for`` surface over the §10 asyncio
 bridge, plus per-request latency marks (``submit_t``, ``first_token_t``,
 ``token_times`` — TTFT and inter-token gaps fall out).
@@ -210,11 +212,14 @@ class RequestHandle:
     prompt is not echoed). ``cancel()`` succeeds only while the request has
     not yet joined the decode batch (cooperative semantics — resident
     work runs to completion); a successful cancel releases anything the
-    request held and the future resolves with ``CancelledError``.
+    request held and the future resolves with ``CancelledError``. A request
+    cancelled after its prefill has streamed the first token, but before
+    it joined, yields that token and then raises ``CancelledError``.
     ``truncated`` is set when the sequence was evicted at cache capacity
     before reaching its token budget.
 
-    Streaming: tokens are pushed per decode tick. ``for tok in handle``
+    Streaming: the first token is pushed when its prefill completes, every
+    later one by a decode tick. ``for tok in handle``
     blocks the calling thread per token; ``async for tok in handle`` rides
     the §10 asyncio bridge and never blocks the event loop. Both raise the
     request's failure (including ``CancelledError``) at the point of
@@ -222,14 +227,16 @@ class RequestHandle:
 
     Latency marks (``time.monotonic`` seconds): ``submit_t`` at submission,
     ``prefill_start_t`` when the body of its first prefill starts,
-    ``prefill_done_t`` when that prefill's result is handed to the join
-    queue, ``first_token_t`` when the first token is delivered (TTFT =
-    ``first_token_t - submit_t``, also exposed as ``.ttft``), and
-    ``token_times`` for every delivered token (inter-token gaps). The
-    prefill marks come from the first prefill only (a resume after
-    preemption leaves them), and stay None for a request that never got
-    that far; for a request that ran, queue wait, prefill and the wait to
-    join the decode batch (which delivers the first token) sum to its TTFT.
+    ``prefill_done_t`` when that prefill completes, ``first_token_t`` when
+    the first token is delivered (TTFT = ``first_token_t - submit_t``, also
+    exposed as ``.ttft``), and ``token_times`` for every delivered token
+    (inter-token gaps). The prefill marks come from the first prefill only
+    (a resume after preemption leaves them), and stay None for a request
+    that never got that far. For a request that ran, TTFT is its queue wait
+    plus its prefill: the prefill pushes the first token itself, so
+    ``prefill_done_t`` -> ``first_token_t`` is only the hand-off to the
+    stream. Joining the decode batch comes after, and delays the second
+    token.
     """
 
     def __init__(
@@ -360,8 +367,10 @@ class RequestHandle:
 
 class _Pending:
     """A request between submission and residency (admit queue / prefill /
-    join queue). ``tokens`` is non-empty iff this is a preempted sequence
-    awaiting resume. Heap key: (deadline or +inf, arrival order)."""
+    join queue). ``tokens`` holds the tokens already streamed: empty until
+    the first prefill completes, so a request that waits or prefills with
+    tokens is a preempted sequence resuming. Heap key: (deadline or +inf,
+    arrival order)."""
 
     __slots__ = (
         "handle", "req", "deadline", "order", "tokens", "cancelled", "stage", "joined",
@@ -377,7 +386,7 @@ class _Pending:
         self.tokens: list[int] = []
         self.cancelled = False
         self.stage = "waiting"  # waiting -> prefill -> join -> (active)
-        self.joined: Optional[tuple] = None  # (cache, first_token, pad)
+        self.joined: Optional[tuple] = None  # prefill result: (cache, first_token, pad)
 
     @property
     def key(self) -> tuple:
@@ -555,7 +564,7 @@ class ServeEngine:
         self._nwaiting = 0  # non-cancelled heap entries
         self._pending_by_rid: dict[int, _Pending] = {}
         self._inflight = 0  # prefill tasks in flight
-        self._joinq: deque = deque()  # (_Pending, cache, first_token, pad_len)
+        self._joinq: deque = deque()  # (_Pending, cache, pad_len)
         self._active: dict[int, _Seq] = {}
         # -- the condition-cycle tick graph (module docs): built once,
         # looped by its weak back-edge, restarted only from idle.
@@ -584,6 +593,7 @@ class ServeEngine:
         self._rejected = 0
         self._deadline_misses = 0
         self._tokens_out = 0
+        self._first_at_prefill = 0  # first tokens pushed as their prefill completed
         self._ticks = 0
         self._occupancy_sum = 0
         self._gc_spans: Optional[_GcSpans] = None
@@ -764,6 +774,8 @@ class ServeEngine:
         banded mode on first use. §13 adds ``preemptions`` (page-pressure
         evictions to the admit queue), ``rejected`` (``QueueFull``
         backpressure), ``deadline_misses`` and the live ``waiting`` depth.
+        ``first_at_prefill`` counts the first tokens pushed as their prefill
+        completed (one per fresh request whose prefill succeeded).
         ``params`` counts the projection leaves the engine serves head-major
         (a scan-stacked leaf counts once; 0 where the model has no GQA).
         """
@@ -780,6 +792,7 @@ class ServeEngine:
                 "breaker_trips": self._breaker_trips,
                 "waiting": self._nwaiting,
                 "tokens_out": self._tokens_out,
+                "first_at_prefill": self._first_at_prefill,
                 "ticks": self._ticks,
                 "tick_replays": plan.replays if plan is not None else 0,
                 "mean_occupancy": occ,
@@ -795,7 +808,8 @@ class ServeEngine:
 
         A cancelled request releases whatever it held (heap entry, in-flight
         prefill result, join-queue cache) — it never reaches a slot, so no
-        pages are ever allocated for it.
+        pages are ever allocated for it. One cancelled from the join queue
+        keeps the first token its prefill streamed.
         """
         with self._lock:
             p = self._pending_by_rid.get(rid)
@@ -894,8 +908,10 @@ class ServeEngine:
             p.joined = (cache, int(jnp.argmax(logits[0, -1])), pad)
 
     def _prefill_done(self, p: _Pending, task: Task) -> None:
-        """Terminal prefill outcome (task ``on_done``): deliver failure or
-        hand the result to the join queue, and feed the circuit breaker."""
+        """Terminal prefill outcome (task ``on_done``): deliver failure, or
+        stream a fresh request's first token and hand the sequence to the
+        join queue (a budget of one completes here), and feed the circuit
+        breaker."""
         handle = p.handle
         exc = task.exception
         if exc is not None:
@@ -933,13 +949,30 @@ class ServeEngine:
                 self._idle.notify_all()
                 exc = self._broken
             else:
-                p.stage = "join"
-                if handle.prefill_done_t is None:
+                if not p.tokens:
+                    # fresh: the first token goes out now, before the join
+                    # queue can see the sequence, so no later token can
+                    # overtake it; a resume already streamed its prefix
                     handle.prefill_done_t = time.monotonic()
-                self._joinq.append((p, cache, first, pad))
-                self._schedule_tick_locked()
-                return
-        handle.future.set_exception(exc)
+                    handle._push(first)
+                    p.tokens = [first]
+                    self._tokens_out += 1
+                    self._first_at_prefill += 1
+                if len(p.tokens) < p.req.max_new_tokens:
+                    p.stage = "join"
+                    self._joinq.append((p, cache, pad))
+                    self._schedule_tick_locked()
+                    return
+                # a budget of one: the first token is the whole answer, so
+                # the sequence never takes a slot
+                self._pending_by_rid.pop(handle.rid, None)
+                self._completed += 1
+                self._pump_locked()
+                self._idle.notify_all()
+        if exc is None:
+            handle.future.set_result(np.asarray(p.tokens, np.int32))
+        else:
+            handle.future.set_exception(exc)
 
     def _schedule_tick_locked(self) -> None:
         """(Re)start the tick graph if no run is in flight.
@@ -1017,41 +1050,32 @@ class ServeEngine:
 
     def _tick_body(self) -> None:
         tracer = self.tracer  # one sink for the whole tick, even across close()
-        # 1. join freshly prefilled sequences into free slots (the join
-        #    claims pages for the prefilled prompt only)
+        # 1. join prefilled sequences into free slots (the join claims pages
+        #    for the prefilled prompt only). Every token a joining sequence
+        #    has was streamed already; the last is its next feed
         with _span(tracer, "serve.tick.join") as late:
             with self._lock:
                 joins = []
                 while self._joinq:
-                    p, cache, first, pad = self._joinq[0]
+                    p, cache, pad = self._joinq[0]
                     slot = self.kv.alloc(self.kv.pages_for(pad))
                     if slot is None:  # lookahead prefills wait for slot/pages
                         break
                     self._joinq.popleft()
                     self._pending_by_rid.pop(p.handle.rid, None)
                     p.stage = "active"
-                    if p.tokens:  # resumed sequence: prefix already delivered
-                        seq = _Seq(
-                            p,
-                            list(p.tokens),
-                            p.handle.prompt_len + len(p.tokens) - 1,
-                            p.req.max_new_tokens - len(p.tokens),
-                            slot,
-                        )
-                    else:
-                        seq = _Seq(p, [first], p.handle.prompt_len, p.req.max_new_tokens - 1, slot)
-                        self._tokens_out += 1  # the prefill-produced first token
-                        p.handle._push(first)
-                    self._active[slot] = seq
+                    n = len(p.tokens)
+                    self._active[slot] = _Seq(
+                        p, list(p.tokens), p.handle.prompt_len + n - 1,
+                        p.req.max_new_tokens - n, slot,
+                    )
                     joins.append((slot, cache, pad, p.handle.rid))
             for slot, cache, pad, _rid in joins:
                 self.kv.write(slot, cache, pad)  # tick chain serializes buffers
             late["joined"] = [j[3] for j in joins]
 
-        retired: list = []
         with _span(tracer, "serve.tick.prepare") as late:
             with self._lock:
-                self._retire_locked(retired)  # max_new_tokens == 1 finishes at join
                 # 1b. back every lane's write position with a physical page;
                 #     on page pressure preempt the youngest resident (oldest
                 #     sequences grow first, so the victim order is stable)
@@ -1068,7 +1092,6 @@ class ServeEngine:
                     # the join queue refilled, else the cycle drains
                     self._pump_locked()
                     self._idle.notify_all()
-                    self._resolve(retired)
                     return
                 tok_np = np.zeros((self.kv.max_slots, 1, 1), np.int32)
                 idx_np = np.zeros((self.kv.max_slots,), np.int32)
@@ -1097,7 +1120,7 @@ class ServeEngine:
 
         # 3. apply results, retire finished/evicted, admit more work
         with _span(tracer, "serve.tick.apply") as late:
-            pushes = []
+            pushes, retired = [], []
             with self._lock:
                 for slot, seq in list(self._active.items()):
                     tok = int(next_np[slot, 0])

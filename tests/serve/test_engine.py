@@ -536,3 +536,201 @@ def test_transient_prefill_failures_leave_outputs_bit_identical():
         assert list(map(int, out)) == ref  # bit-identical despite retries
     assert s["pool"]["retries"] >= 2  # the recovery went through §14 retry
     assert s["breaker_trips"] == 0  # transient, never sustained
+
+
+# ---------------------------------------------------------------------------
+# The first token goes out when its prefill completes; the join only places
+# the sequence in a slot
+# ---------------------------------------------------------------------------
+
+
+def _held_resident(engine, prompt, budget):
+    """Submit a resident and hold every decode tick at its dispatch until the
+    returned event is set: the resident has joined and streamed its first
+    token, and the engine's slot stays taken mid-decode."""
+    entered, release = threading.Event(), threading.Event()
+    real_tick = engine._tick_jit
+
+    def held_tick(*args):
+        entered.set()
+        release.wait(120)
+        return real_tick(*args)
+
+    engine._tick_jit = held_tick
+    h = engine.submit(prompt, budget)
+    assert entered.wait(300), "the resident's first tick never dispatched"
+    return h, release
+
+
+def test_first_token_streams_while_waiting_to_join():
+    """A request prefilled behind a resident of the only slot has its first
+    token while the resident is still decoding; it joins only when the
+    slot frees, and both answers equal the sequential reference."""
+    cfg, model, params = _build("tinyllama-1.1b")
+    MAX_LEN = 24
+    rng = np.random.default_rng(21)
+    prompts = [rng.integers(0, cfg.vocab_size, size=5).astype(np.int32) for _ in range(2)]
+    budgets = [6, 4]
+    refs = [sequential_decode(model, params, p, b, MAX_LEN) for p, b in zip(prompts, budgets)]
+    engine = ServeEngine(model, params, max_slots=1, max_len=MAX_LEN)
+    try:
+        resident, release = _held_resident(engine, prompts[0], budgets[0])
+        waiting = engine.submit(prompts[1], budgets[1])
+        stream = waiting.iter_tokens(timeout=300)
+        assert next(stream) == refs[1][0]
+        assert waiting.prefill_done_t <= waiting.first_token_t
+        # the resident holds the slot and has only its own first token
+        assert not resident.done() and len(resident.token_times) == 1
+        assert len(engine._joinq) == 1 and engine.stats()["kv"]["live"] == 1
+        release.set()
+        assert [refs[1][0]] + list(stream) == refs[1]
+        assert list(map(int, waiting.result(0))) == refs[1]
+        assert list(map(int, resident.result(300))) == refs[0]
+        assert resident.token_times[-1] < waiting.token_times[1]  # joined after
+    finally:
+        release.set()
+        engine.close(drain=False)
+
+
+def test_budget_of_one_completes_at_prefill_without_a_slot():
+    """With the only slot held, a one-token request completes at its
+    prefill: no slot, no page and no cache write."""
+    cfg, model, params = _build("tinyllama-1.1b")
+    MAX_LEN = 24
+    rng = np.random.default_rng(22)
+    prompts = [rng.integers(0, cfg.vocab_size, size=6).astype(np.int32) for _ in range(2)]
+    ref = sequential_decode(model, params, prompts[1], 1, MAX_LEN)
+    engine = ServeEngine(model, params, max_slots=1, max_len=MAX_LEN)
+    try:
+        resident, release = _held_resident(engine, prompts[0], 5)
+        writes = []
+        real_write = engine.kv.write
+        engine.kv.write = lambda *a: (writes.append(a[0]), real_write(*a))[1]
+        before = engine.stats()["kv"]
+        one = engine.submit(prompts[1], 1)
+        assert list(map(int, one.result(300))) == ref
+        assert list(one) == ref
+        after = engine.stats()
+        assert not resident.done()
+        assert after["completed"] == 1 and after["first_at_prefill"] == 2
+        assert after["kv"]["page_allocs"] == before["page_allocs"]
+        assert after["kv"]["allocs"] == before["allocs"]
+        assert writes == []
+        release.set()
+        assert len(resident.result(300)) == 5
+        engine.drain(60)
+        assert engine.stats()["kv"]["pages_live"] == 0
+    finally:
+        release.set()
+        engine.close(drain=False)
+
+
+def test_cancel_after_first_token_before_join():
+    """A request whose first token streamed but which has not joined can
+    still be cancelled: its stream yields that token and then raises, its
+    cache never takes a page, and the resident's answer is unchanged."""
+    cfg, model, params = _build("tinyllama-1.1b")
+    MAX_LEN = 24
+    rng = np.random.default_rng(23)
+    prompts = [rng.integers(0, cfg.vocab_size, size=5).astype(np.int32) for _ in range(2)]
+    ref = sequential_decode(model, params, prompts[0], 6, MAX_LEN)
+    first = sequential_decode(model, params, prompts[1], 1, MAX_LEN)
+    engine = ServeEngine(model, params, max_slots=1, max_len=MAX_LEN)
+    try:
+        resident, release = _held_resident(engine, prompts[0], 6)
+        h = engine.submit(prompts[1], 5)
+        assert [next(h.iter_tokens(timeout=300))] == first
+        assert h.cancel()
+        got = []
+        with pytest.raises(CancelledError):
+            for tok in h:
+                got.append(tok)
+        assert got == first
+        assert len(engine._joinq) == 0
+        release.set()
+        assert list(map(int, resident.result(300))) == ref
+        engine.drain(60)
+        st = engine.stats()
+        assert st["kv"]["pages_live"] == 0 and st["completed"] == 1
+        assert not h.cancel()  # already resolved
+    finally:
+        release.set()
+        engine.close(drain=False)
+
+
+def test_streams_under_preemption_hold_each_token_once():
+    """Under page pressure a preempted request resumes by re-prefill, which
+    streams nothing: every handle's stream equals its result and the
+    reference, with no duplicated first token."""
+    cfg, model, params = _build("tinyllama-1.1b")
+    MAX_LEN = 24
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, cfg.vocab_size, size=5).astype(np.int32) for _ in range(3)]
+    budgets = [12, 11, 10]
+    refs = [
+        sequential_decode(model, params, p, b, MAX_LEN) for p, b in zip(prompts, budgets)
+    ]
+    with ServeEngine(
+        model, params, max_slots=2, max_len=MAX_LEN, page_size=4, num_pages=6
+    ) as engine:
+        handles = [engine.submit(p, b) for p, b in zip(prompts, budgets)]
+        streams = [list(h.iter_tokens(timeout=300)) for h in handles]
+        engine.drain(60)
+        stats = engine.stats()
+    assert stats["preemptions"] >= 1
+    for h, s, ref in zip(handles, streams, refs):
+        assert s == list(map(int, h.result(0))) == ref
+        assert len(h.token_times) == len(ref)
+    assert stats["first_at_prefill"] == 3  # resumes push nothing
+    assert stats["tokens_out"] == sum(budgets)
+
+
+def test_first_at_prefill_counts_fresh_prefills():
+    """``stats()["first_at_prefill"]`` counts one first token per fresh
+    request whose prefill completed: not one cancelled mid-prefill."""
+    cfg, model, params = _build("tinyllama-1.1b")
+    rng = np.random.default_rng(24)
+    prompts = [rng.integers(0, cfg.vocab_size, size=4).astype(np.int32) for _ in range(4)]
+    budgets = [1, 3, 2, 5]
+    with ThreadPool(1, name="serve-fp") as pool:
+        engine = ServeEngine(model, params, max_slots=2, max_len=16, pool=pool)
+        gate = threading.Event()
+        pool.submit(lambda: gate.wait(30))
+        dropped = engine.submit(prompts[0], 4)  # prefill queued behind the gate
+        assert dropped.cancel()
+        gate.set()
+        outs = engine.generate(prompts, budgets, timeout=300)
+        engine.drain(60)
+        st = engine.stats()
+        engine.close()
+    assert [len(o) for o in outs] == budgets
+    assert st["first_at_prefill"] == len(prompts)
+    assert st["tokens_out"] == sum(budgets)
+    assert st["completed"] == len(prompts)
+
+
+def test_streams_stay_ordered_under_thread_churn():
+    """Prefills push first tokens from pool workers while ticks push the
+    rest: with more workers than slots and a short switch interval, every
+    stream still equals its result, one token per budget."""
+    import sys
+
+    cfg, model, params = _build("tinyllama-1.1b")
+    rng = np.random.default_rng(25)
+    prompts = [rng.integers(0, cfg.vocab_size, size=4).astype(np.int32) for _ in range(12)]
+    budgets = [int(b) for b in rng.integers(1, 5, size=12)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with ThreadPool(4, name="serve-churn") as pool:
+            with ServeEngine(model, params, max_slots=2, max_len=16, pool=pool) as engine:
+                handles = [engine.submit(p, b) for p, b in zip(prompts, budgets)]
+                streams = [list(h.iter_tokens(timeout=300)) for h in handles]
+                engine.drain(60)
+                st = engine.stats()
+    finally:
+        sys.setswitchinterval(interval)
+    for h, s, b in zip(handles, streams, budgets):
+        assert s == list(map(int, h.result(0))) and len(s) == b
+    assert st["first_at_prefill"] == len(prompts)
+    assert st["tokens_out"] == sum(budgets)

@@ -85,9 +85,11 @@ def test_tick_phases_nest_in_their_decode_tick_in_order(tmp_path):
     assert owned == len(phases)  # every phase span sits in exactly one tick
     assert len(decoded) == stats["ticks"]
     joined = [rid for e in phases if e["name"] == "serve.tick.join" for rid in e["args"]["joined"]]
-    assert sorted(joined) == sorted(h.rid for h in handles)
-    # the prefill's first token is pushed at the join, every other in apply
-    assert sum(d["serve.tick.apply"]["tokens"] for d in decoded) + len(joined) == sum(budgets)
+    # a budget of one completes at its prefill and never joins
+    assert sorted(joined) == sorted(h.rid for h, b in zip(handles, budgets) if b > 1)
+    # the prefill pushes each first token, the ticks' apply every other
+    assert stats["first_at_prefill"] == len(handles)
+    assert sum(d["serve.tick.apply"]["tokens"] for d in decoded) + len(handles) == sum(budgets)
     assert sum(e["args"]["retired"] for e in phases if e["name"] == "serve.tick.apply") <= 5
     assert all(1 <= d["serve.tick.prepare"]["live"] <= 2 for d in decoded)
 
